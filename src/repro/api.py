@@ -26,6 +26,7 @@ config object; ``mesh`` accepts a live ``jax.sharding.Mesh``, a tuple of
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Optional, Sequence, Tuple, Union
 
 import jax
@@ -147,12 +148,18 @@ class Executable:
 
     # -------------------------- parameters ---------------------------
     def init_params(self, key=None, dtype=None) -> PyTree:
-        """Initialise params and place them per the plan's shardings."""
+        """Initialise params directly in the plan's shardings: each
+        device materialises only its own shards, so a model larger than
+        one device's memory never lands whole on device 0."""
         from repro.models import registry as REG
         if key is None or isinstance(key, int):
             key = jax.random.PRNGKey(key or 0)
-        params = REG.init_params(self.arch, key, dtype or self.dtype)
-        return self.shard_params(params)
+        init = functools.partial(REG.init_params, self.arch,
+                                 dtype=dtype or self.dtype)
+        shardings = self.plan.param_shardings(jax.eval_shape(init, key),
+                                              self.mesh)
+        with self.mesh:
+            return jax.jit(init, out_shardings=shardings)(key)
 
     def shard_params(self, params: PyTree) -> PyTree:
         """device_put with NamedShardings derived from the ShardingPlan."""

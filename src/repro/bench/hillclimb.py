@@ -19,8 +19,8 @@ def main():
     mode = sys.argv[3] if len(sys.argv) > 3 else "optimized"
     if mode == "baseline":
         os.environ["REPRO_EXPLICIT_SPMD"] = "0"
-    # importing dryrun forces the 512-device host platform (via
-    # testing.mesh_fixtures: appends to XLA_FLAGS, never overwrites)
+    # importing dryrun pins the CPU platform and forces 512 host devices
+    # (via testing.mesh_fixtures: appends to XLA_FLAGS, never overwrites)
     from repro.launch.dryrun import lower_cell
     from repro.launch import hlo_analysis as H
 

@@ -83,7 +83,7 @@ def _flash_kernel_q8(q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     k_scale: jax.Array = None, v_scale: jax.Array = None,
                     bq: int = 512, bk: int = 512, causal: bool = True,
-                    window: int = 0, interpret: bool = True) -> jax.Array:
+                    window: int = 0, interpret: bool) -> jax.Array:
     """q: [BH, S, D]; k, v: [BH, T, D] (KV already broadcast across groups).
     ``k_scale``/``v_scale``: optional [BH, T, 1] f32 per-token scales for
     int8 ``k``/``v`` (dequant fused in-kernel)."""

@@ -17,8 +17,12 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _mlstm_kernel(q_ref, k_ref, v_ref, i_ref, f_ref, o_ref,
+def _mlstm_kernel(q_ref, k_ref, v_ref, ic_ref, fc_ref, ir_ref, fr_ref, o_ref,
                   c_ref, n_ref, m_ref, *, bq: int):
+    """Gates arrive twice, as a column ``[bq, 1]`` and as a row
+    ``[1, bq]`` block, so every step stays 2-D with no transpose: the
+    cumulative forget-gate sums come out of masked reductions over the
+    causal mask in whichever orientation the use needs."""
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -30,49 +34,68 @@ def _mlstm_kernel(q_ref, k_ref, v_ref, i_ref, f_ref, o_ref,
     q = q_ref[0].astype(jnp.float32)  # [bq, d]
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
-    it = i_ref[0].astype(jnp.float32)  # [bq]
-    logf = jax.nn.log_sigmoid(f_ref[0].astype(jnp.float32))  # [bq]
+    it_c = ic_ref[0].astype(jnp.float32)  # [bq, 1]
+    it_r = ir_ref[0].astype(jnp.float32)  # [1, bq]
+    logf_c = jax.nn.log_sigmoid(fc_ref[0].astype(jnp.float32))
+    logf_r = jax.nn.log_sigmoid(fr_ref[0].astype(jnp.float32))
 
-    F = jnp.cumsum(logf)  # [bq]
-    m_carry = m_ref[0]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bq), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bq), 1)
+    causal = cols <= rows
+    # inclusive prefix sums of log f, F[i] = Σ_{j<=i}, as column and row
+    F_c = jnp.sum(jnp.where(causal, logf_r, 0.0), axis=1, keepdims=True)
+    F_r = jnp.sum(jnp.where(rows <= cols, logf_c, 0.0), axis=0, keepdims=True)
+    # chunk-level quantities are scalars: a [1, 1] vector would need a
+    # broadcast in sublanes and lanes at once, which Mosaic rejects
+    Fe = jnp.sum(logf_r, axis=1, keepdims=True)[0, 0]  # chunk total
+    m_carry = m_ref[...][0, 0]
     # intra-chunk decay bias D_ij = F_i - F_j + i_j  (j <= i)
-    bias = F[:, None] - F[None, :] + it[None, :]
-    causal = jax.lax.broadcasted_iota(jnp.int32, (bq, bq), 1) <= \
-        jax.lax.broadcasted_iota(jnp.int32, (bq, bq), 0)
-    bias = jnp.where(causal, bias, NEG_INF)
-    w_state = F + m_carry  # log-coefficient of carried state per row
-    m_i = jnp.maximum(jnp.maximum(jnp.max(bias, axis=-1), w_state), NEG_INF)
+    bias = jnp.where(causal, F_c - F_r + it_r, NEG_INF)
+    w_state = F_c + m_carry  # [bq, 1] log-coefficient of the carried state
+    m_i = jnp.maximum(jnp.maximum(jnp.max(bias, axis=1, keepdims=True),
+                                  w_state), NEG_INF)
 
-    scores = (q @ k.T) * jnp.exp(bias - m_i[:, None])  # [bq, bq]
-    s_coef = jnp.exp(w_state - m_i)  # [bq]
-    num = scores @ v + s_coef[:, None] * (q @ c_ref[...])
-    den = jnp.sum(scores, axis=-1) + s_coef * (q @ n_ref[...])
+    qk = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    scores = qk * jnp.exp(bias - m_i)  # [bq, bq]
+    s_coef = jnp.exp(w_state - m_i)  # [bq, 1]
+    num = (jnp.dot(scores, v, preferred_element_type=jnp.float32)
+           + s_coef * jnp.dot(q, c_ref[...], preferred_element_type=jnp.float32))
+    den = (jnp.sum(scores, axis=1, keepdims=True)
+           + s_coef * jnp.sum(q * n_ref[...], axis=1, keepdims=True))
     den = jnp.maximum(jnp.abs(den), jnp.exp(-m_i))
-    o_ref[0] = (num / den[:, None]).astype(o_ref.dtype)
+    o_ref[0] = (num / den).astype(o_ref.dtype)
 
     # fold chunk into state
-    Fe = F[-1]
-    w_log = Fe - F + it  # [bq]
-    m_new = jnp.maximum(jnp.max(w_log), Fe + m_carry)
-    wts = jnp.exp(w_log - m_new)
+    w_log = Fe - F_c + it_c  # [bq, 1]
+    m_new = jnp.maximum(jnp.max(w_log, axis=0, keepdims=True)[0, 0],
+                        Fe + m_carry)
+    kw = k * jnp.exp(w_log - m_new)  # [bq, d]
     carry = jnp.exp(Fe + m_carry - m_new)
-    c_ref[...] = carry * c_ref[...] + (k * wts[:, None]).T @ v
-    n_ref[...] = carry * n_ref[...] + jnp.sum(k * wts[:, None], axis=0)
+    c_ref[...] = carry * c_ref[...] + jax.lax.dot_general(
+        kw, v, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    n_ref[...] = carry * n_ref[...] + jnp.sum(kw, axis=0, keepdims=True)
     m_ref[...] = jnp.full_like(m_ref, m_new)
 
 
 @functools.partial(jax.jit, static_argnames=("bq", "interpret"))
 def mlstm_chunkwise(q: jax.Array, k: jax.Array, v: jax.Array,
                     it: jax.Array, ft: jax.Array, *,
-                    bq: int = 256, interpret: bool = True) -> jax.Array:
+                    bq: int = 256, interpret: bool) -> jax.Array:
     """q,k,v: [BH, S, D]; it, ft: [BH, S] gate pre-activations. -> [BH, S, D].
 
     k is expected pre-scaled by 1/sqrt(D) (as in models/recurrent.py).
+    The gates enter the kernel as ``[BH, S, 1]`` and ``[BH, 1, S]``
+    views, whose ``(1, bq, 1)`` / ``(1, 1, bq)`` blocks meet the TPU
+    lowering's rule (last two block dims (8, 128)-aligned or equal to the
+    array's).
     """
     bh, s, d = q.shape
     bq = min(bq, s)
     assert s % bq == 0
     grid = (bh, s // bq)
+    col = pl.BlockSpec((1, bq, 1), lambda i, j: (i, j, 0))
+    row = pl.BlockSpec((1, 1, bq), lambda i, j: (i, 0, j))
     return pl.pallas_call(
         functools.partial(_mlstm_kernel, bq=bq),
         grid=grid,
@@ -80,15 +103,14 @@ def mlstm_chunkwise(q: jax.Array, k: jax.Array, v: jax.Array,
             pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, bq), lambda i, j: (i, j)),
-            pl.BlockSpec((1, bq), lambda i, j: (i, j)),
+            col, col, row, row,
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[
             pltpu.VMEM((d, d), jnp.float32),
-            pltpu.VMEM((d,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
+            pltpu.VMEM((1, d), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, it, ft)
+    )(q, k, v, it[..., None], ft[..., None], it[:, None, :], ft[:, None, :])

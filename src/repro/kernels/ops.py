@@ -1,5 +1,9 @@
-"""Public jit'd wrappers: dispatch Pallas kernels on TPU, interpret-mode
-Pallas on CPU (validation), with the jnp references always available."""
+"""Public jit'd wrappers: the one place that picks interpret mode.
+
+Every Pallas kernel entry point takes ``interpret`` without a default;
+these wrappers pass ``not _on_tpu()``, so a kernel is compiled by Mosaic
+on TPU and runs in the Pallas interpreter on CPU (validation). The jnp
+references are re-exported alongside."""
 from __future__ import annotations
 
 import jax

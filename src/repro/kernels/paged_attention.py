@@ -19,8 +19,8 @@ scale page are DMA'd together and rehydrated in VMEM right before the
 dot, so the fp extent never exists in HBM (the whole point of the int8
 cache: HBM traffic per page drops ~4x for bf16→int8-and-scale).
 
-Runs in interpret mode off-TPU (the default), matching the other kernels
-in this package; `kernels/ref.py:paged_attention_ref` is the jnp oracle.
+Callers go through ``kernels/ops.py``, which picks interpret mode by
+platform; `kernels/ref.py:paged_attention_ref` is the jnp oracle.
 """
 from __future__ import annotations
 
@@ -92,7 +92,7 @@ def _paged_kernel_q8(lens_ref, table_ref, q_ref, k_ref, v_ref,
 def paged_attention(q: jax.Array, kp: jax.Array, vp: jax.Array,
                     page_table: jax.Array, lengths: jax.Array, *,
                     k_scale: jax.Array = None, v_scale: jax.Array = None,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool) -> jax.Array:
     """q: [B, H, D]; kp, vp: [P, ps, G, D] page pools;
     page_table: [B, M] int32 physical page per logical block;
     lengths: [B] int32 valid kv count per row (positions >= length are

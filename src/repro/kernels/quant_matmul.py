@@ -11,8 +11,8 @@ scale multiplies the accumulated ``[tr, tm]`` tile exactly once at flush,
 not per contraction step. Same ⟨Tm,Tn,Tr⟩ tiling and double-buffered
 pipeline structure as kernels/xfer_matmul.py.
 
-Runs in interpret mode off-TPU; ``kernels/ref.py:quant_matmul_ref`` is
-the jnp oracle.
+Callers go through ``kernels/ops.py``, which picks interpret mode by
+platform; ``kernels/ref.py:quant_matmul_ref`` is the jnp oracle.
 """
 from __future__ import annotations
 
@@ -44,7 +44,7 @@ def _quant_matmul_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, n_steps: int):
 @functools.partial(jax.jit, static_argnames=("tr", "tm", "tn", "interpret"))
 def quant_matmul(x: jax.Array, w_q: jax.Array, scale: jax.Array, *,
                  tr: int = 256, tm: int = 256, tn: int = 256,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: bool) -> jax.Array:
     """x: [R, N] fp @ w_q: [N, M] int8 with scale: [1, M] f32 -> [R, M].
 
     ``w_q``/``scale`` are a per-channel :class:`repro.quant.QTensor`'s

@@ -37,7 +37,7 @@ def _matmul_kernel(x_ref, w_ref, o_ref, acc_ref, *, n_steps: int):
 
 @functools.partial(jax.jit, static_argnames=("tr", "tm", "tn", "interpret"))
 def xfer_matmul(x: jax.Array, w: jax.Array, *, tr: int = 256, tm: int = 256,
-                tn: int = 256, interpret: bool = True) -> jax.Array:
+                tn: int = 256, interpret: bool) -> jax.Array:
     """x: [R, N] @ w: [N, M] -> [R, M] with explicit ⟨Tm,Tn,Tr⟩ tiling.
 
     (Tc is folded into Tr: an LM matmul's spatial extent is 1-D, DESIGN §4.)

@@ -4,9 +4,10 @@ force_host_device_count(512)
 # ^ MUST precede the first XLA backend creation (the device count locks
 # then — merely importing jax, as the repro import chain above does, is
 # fine as long as nothing touches jax.devices() at module scope). Appends
-# to (never overwrites) user-set XLA_FLAGS, and no-ops with a warning when
-# a backend already exists in this process. This is dry-run-only;
-# tests/benches see the real (1-CPU) device count.
+# to (never overwrites) user-set XLA_FLAGS, pins the CPU platform (the
+# simulated devices live on the host; the chip stays free), and no-ops
+# with a warning when a backend already exists in this process. This is
+# dry-run-only; tests/benches see the real device count.
 
 import argparse  # noqa: E402
 import json  # noqa: E402
@@ -123,10 +124,7 @@ def run_cell(arch_id: str, shape_id: str, multi_pod: bool, outdir: pathlib.Path,
     t_compile = time.time() - t0
 
     mem = _mem_dict(compiled.memory_analysis())
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # jax<=0.4.x: one dict per device set
-        cost = cost[0] if cost else {}
-    cost = dict(cost)
+    cost = dict(compiled.cost_analysis() or {})
     hlo_text = compiled.as_text()
     coll = parse_collectives(hlo_text)
     t0 = time.time()

@@ -4,34 +4,20 @@ Defined as functions (never module-level constants) so importing this
 module never touches JAX device state. The dry-run entrypoint
 (`launch/dryrun.py`) forces 512 host devices *before* any JAX import;
 everything else sees the real device count.
-
-``make_mesh`` papers over the ``axis_types`` API gap: newer JAX exposes
-``jax.sharding.AxisType`` and ``jax.make_mesh(..., axis_types=...)``;
-older releases (<= 0.4.x) have neither, and plain ``Auto`` axes are the
-default there anyway.
 """
 from __future__ import annotations
 
 from typing import Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5 (older releases default every axis to Auto)
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover - exercised on jax 0.4.x
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str],
               devices: Optional[Sequence] = None) -> Mesh:
-    """Version-portable ``jax.make_mesh`` with Auto axis types."""
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if AxisType is not None:
-        kwargs["axis_types"] = (AxisType.Auto,) * len(axes)
-    return jax.make_mesh(tuple(shape), tuple(axes), **kwargs)
+    """``jax.make_mesh`` with Auto axis types (GSPMD propagation)."""
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -18,6 +18,7 @@ import numpy as np
 from repro.api import plan
 from repro.configs import ARCH_IDS
 from repro.configs.base import ShapeConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import ServeConfig
 from repro.serving.engine import Request
 from repro.serving.sampler import SamplingParams
@@ -50,6 +51,7 @@ def main():
             temperature=1.0 if args.temperature is None else args.temperature,
             top_k=args.top_k)
 
+    enable_compile_cache()
     shape = ShapeConfig("serve_cli", args.max_len, args.slots, "decode")
     force_xfer = {"on": True, "off": False, "auto": None}[args.xfer]
     xplan = plan(args.arch, shape, reduced=args.reduced, force_xfer=force_xfer)

@@ -18,6 +18,7 @@ import numpy as np
 from repro.api import plan
 from repro.configs import ARCH_IDS
 from repro.configs.base import ShapeConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import adamw as OPT
 
 
@@ -36,6 +37,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     shape = ShapeConfig("train_cli", args.seq, args.batch, "train")
     force_xfer = {"on": True, "off": False, "auto": None}[args.xfer]
     xplan = plan(args.arch, shape, reduced=args.reduced, force_xfer=force_xfer)

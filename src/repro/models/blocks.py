@@ -74,8 +74,8 @@ def _kv_read(cache: dict, name: str, dtype) -> jax.Array:
 # Paged decode read-path implementation (see serving/pages.py):
 # "gather" reads pages with a jnp gather and runs the same attention the
 # dense grid runs (bit-exact with it when page_size divides max_len);
-# "kernel" dispatches the Pallas paged-attention kernel
-# (kernels/paged_attention.py — interpret mode off-TPU). Overridable for
+# "kernel" dispatches the Pallas paged-attention kernel through
+# kernels/ops.py (compiled on TPU, interpreted elsewhere). Overridable for
 # experiments, like lm.set_remat_policy.
 _PAGED_ATTN_IMPL = "gather"
 
@@ -132,11 +132,11 @@ def _paged_decode_attention(ctx, q, k, v, cache: dict,
     else:
         new_cache = {"kp": write(cache["kp"], k), "vp": write(cache["vp"], v)}
     if _PAGED_ATTN_IMPL == "kernel" and s == 1:
-        from repro.kernels.paged_attention import paged_attention
-        o = paged_attention(q[:, 0], new_cache["kp"], new_cache["vp"],
-                            page_table, pos[:, 0] + 1,
-                            k_scale=new_cache.get("kps"),
-                            v_scale=new_cache.get("vps"))[:, None]
+        from repro.kernels import ops
+        o = ops.paged_attn(q[:, 0], new_cache["kp"], new_cache["vp"],
+                           page_table, pos[:, 0] + 1,
+                           k_scale=new_cache.get("kps"),
+                           v_scale=new_cache.get("vps"))[:, None]
         return o, new_cache
 
     def flat(name):
@@ -563,10 +563,7 @@ def _moe_apply_sharded(arch: ArchConfig, p: dict, h: jax.Array, ctx,
     """GShard-style EP: local top-k dispatch → all-to-all over the expert
     axis → local expert FFNs → reverse all-to-all → local combine."""
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     b, s, d = h.shape
     e, k = arch.num_experts, arch.top_k
@@ -598,10 +595,7 @@ def _moe_apply_sharded(arch: ArchConfig, p: dict, h: jax.Array, ctx,
     wnames = ("w_gate", "w_up", "w_down") if has_gate else ("w_up", "w_down")
     kwargs = dict(mesh=ctx.mesh, in_specs=(hs, rs) + (ws,) * len(wnames),
                   out_specs=hs)
-    try:
-        fn = shard_map(local, check_vma=False, **kwargs)
-    except TypeError:  # pragma: no cover
-        fn = shard_map(local, check_rep=False, **kwargs)
+    fn = shard_map(local, check_vma=False, **kwargs)
     y = fn(h, p["router"], *(moe_p[n] for n in wnames))
     if "shared" in p:
         y = y + L.mlp_apply(p["shared"], h, arch.mlp, ctx)

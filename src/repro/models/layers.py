@@ -177,10 +177,7 @@ def attention_sharded(ctx, q, k, v, q_pos, kv_pos, kv_valid=None, *,
             or not explicit_spmd_enabled()):
         return attention(q, k, v, q_pos, kv_pos, kv_valid, causal=causal,
                          window=window, prefix_len=prefix_len, q_block=q_block)
-    try:
-        from jax import shard_map  # jax >= 0.6
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     b, s, h, d = q.shape
     t, g = k.shape[1], k.shape[2]
@@ -213,10 +210,7 @@ def attention_sharded(ctx, q, k, v, q_pos, kv_pos, kv_valid=None, *,
 
     kwargs = dict(mesh=ctx.mesh, in_specs=(qs, ks, ks, ps, kp, kvd, pls),
                   out_specs=qs)
-    try:
-        fn = shard_map(local, check_vma=False, **kwargs)  # jax >= 0.8
-    except TypeError:  # pragma: no cover
-        fn = shard_map(local, check_rep=False, **kwargs)
+    fn = shard_map(local, check_vma=False, **kwargs)
     return fn(q, k, v, q_pos, kv_pos, kv_valid, prefix_len)
 
 
@@ -237,10 +231,7 @@ def decode_attention_sharded(ctx, q, k, v, q_pos, kv_pos, kv_valid, *,
     if ctx is None or ctx.mesh is None or not explicit_spmd_enabled():
         return attention(q, k, v, q_pos, kv_pos, kv_valid, causal=causal,
                          window=window, prefix_len=prefix_len)
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     b, s, h, d = q.shape
     t, g = k.shape[1], k.shape[2]
@@ -280,10 +271,7 @@ def decode_attention_sharded(ctx, q, k, v, q_pos, kv_pos, kv_valid, *,
 
     kwargs = dict(mesh=ctx.mesh,
                   in_specs=(qs, ks, ks, pqs, pks, kvs, pls), out_specs=qs)
-    try:
-        fn = shard_map(local, check_vma=False, **kwargs)
-    except TypeError:  # pragma: no cover
-        fn = shard_map(local, check_rep=False, **kwargs)
+    fn = shard_map(local, check_vma=False, **kwargs)
     return fn(q, k, v, q_pos, kv_pos, kv_valid, prefix_len)
 
 

@@ -22,6 +22,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.models import layers as L
@@ -58,7 +59,8 @@ def rglru_init(key, arch: ArchConfig, dtype=jnp.float32) -> dict:
         # block-diagonal per-head input/recurrence gates
         "gate_w": L.dense_init(ks[2], (heads, hw, 2 * hw), 1, dtype),
         "gate_b": jnp.zeros((heads, 2 * hw), dtype),
-        "a_param": jnp.linspace(0.9, 0.999, w).astype(dtype),  # Λ init
+        # Λ init: a host constant, so jitted and eager init agree bitwise
+        "a_param": jnp.asarray(np.linspace(0.9, 0.999, w), dtype),
         "w_out": L.dense_init(ks[3], (w, d), 0, dtype),
     }
     if arch.d_ff and arch.mlp != "none":

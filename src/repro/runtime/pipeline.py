@@ -53,10 +53,7 @@ def pipelined_forward(arch: ArchConfig, params: PyTree, tokens: jax.Array,
     replicated across stages. Returns hidden states [B, S, D].
     """
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     stages = dict(mesh.shape)[stage_axis]
     b, s = tokens.shape
@@ -103,10 +100,7 @@ def pipelined_forward(arch: ArchConfig, params: PyTree, tokens: jax.Array,
 
     kwargs = dict(mesh=mesh, in_specs=(P(*([None] * 4)), body_specs),
                   out_specs=P(*([None] * 4)))
-    try:
-        fn = shard_map(run, check_vma=False, **kwargs)
-    except TypeError:  # pragma: no cover
-        fn = shard_map(run, check_rep=False, **kwargs)
+    fn = shard_map(run, check_vma=False, **kwargs)
     outs = fn(xs, params["body"])
     hidden = outs.reshape(b, s, arch.d_model)
     return L.rms_norm(hidden, params["final_norm"])

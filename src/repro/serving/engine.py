@@ -30,6 +30,7 @@ from typing import Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
 from repro.core.execution_plan import ExecutionPlan
@@ -83,11 +84,8 @@ class IncompleteDrainError(RuntimeError):
 
 def _record_ready(rec) -> bool:
     """True when every leaf of a step record has finished on device
-    (non-blocking; conservatively False if the runtime lacks is_ready)."""
-    try:
-        return all(leaf.is_ready() for leaf in jax.tree.leaves(rec))
-    except AttributeError:
-        return False
+    (non-blocking)."""
+    return all(leaf.is_ready() for leaf in jax.tree.leaves(rec))
 
 
 class ServingEngine:
@@ -224,21 +222,23 @@ class ServingEngine:
             enc_dtype=dtype, table_len=table_len, draft_caches=draft_caches)
         if self.plan is not None:
             from repro.core.xfer import tree_shardings
+            replicated = NamedSharding(self.mesh, P())
             if spec is not None:
                 # target params take the plan's shardings; the draft is
-                # small by construction and stays replicated (its dims
-                # resolve under the same ctx — non-dividing axes drop)
+                # small by construction and is replicated on every device
                 params = {"target": jax.device_put(
                     params["target"],
                     self.plan.param_shardings(params["target"], self.mesh)),
-                    "draft": params["draft"]}
+                    "draft": jax.device_put(params["draft"], replicated)}
             else:
                 params = jax.device_put(
                     params, self.plan.param_shardings(params, self.mesh))
-            if not paged:
+            if paged:
                 # page pools have no slot axis, so the plan's dense cache
-                # shardings don't apply; the jitted step lets the compiler
-                # place them (gathered reads are resharded on the fly)
+                # shardings don't apply: every device holds the whole pool
+                # (gathered reads are resharded on the fly)
+                self.caches = jax.device_put(self.caches, replicated)
+            else:
                 self.caches = jax.device_put(
                     self.caches,
                     self.plan.cache_shardings(self.caches, self.mesh))

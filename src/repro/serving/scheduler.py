@@ -264,10 +264,7 @@ class _Inflight:
         self.dispatch_wall = dispatch_wall
 
     def ready(self) -> bool:
-        try:
-            return all(leaf.is_ready() for leaf in jax.tree.leaves(self.outs))
-        except AttributeError:  # runtime without is_ready: sync splice
-            return True
+        return all(leaf.is_ready() for leaf in jax.tree.leaves(self.outs))
 
 
 class PrefillFactory:

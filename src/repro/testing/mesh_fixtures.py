@@ -10,7 +10,10 @@ with those two constraints:
   (user-set flags survive; a previous force flag is replaced) and refuses
   to touch the environment once the backend is initialised — the bug the
   old ``launch/dryrun.py`` / ``bench/hillclimb.py`` import-time
-  ``os.environ["XLA_FLAGS"] = ...`` overwrite had.
+  ``os.environ["XLA_FLAGS"] = ...`` overwrite had. It also pins the CPU
+  platform (:func:`pin_cpu_platform`): simulated devices live on the host,
+  and a process that initialised the accelerator instead would hold the
+  chip another process needs (one process per chip).
 * :func:`fake_devices` is the context-managed form for launcher
   entry points (set, run, restore).
 * :func:`run_in_subprocess` runs a script under a fresh XLA client with a
@@ -90,8 +93,26 @@ def _merged_flags(existing: str, n: int) -> str:
     return " ".join(kept)
 
 
+def pin_cpu_platform(env: Optional[Dict[str, str]] = None) -> None:
+    """Keep a process on the CPU backend: ``JAX_PLATFORMS=cpu``.
+
+    ``env`` (a child's environment) is edited as given. Without it the
+    edit targets this process; JAX reads ``JAX_PLATFORMS`` when it is
+    imported, so an already-imported JAX is pinned through its config as
+    well (effective until the backend is created).
+    """
+    if env is not None:
+        env["JAX_PLATFORMS"] = "cpu"
+        return
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        jax.config.update("jax_platforms", "cpu")
+
+
 def force_host_device_count(n: int, env: Optional[Dict[str, str]] = None) -> bool:
-    """Request ``n`` fake host devices by editing ``XLA_FLAGS`` in place.
+    """Request ``n`` fake host devices by editing ``XLA_FLAGS`` in place,
+    and pin the CPU platform they live on.
 
     Appends to the existing value instead of overwriting it. When ``env``
     is None the edit targets ``os.environ`` and is refused (returns False,
@@ -112,7 +133,10 @@ def force_host_device_count(n: int, env: Optional[Dict[str, str]] = None) -> boo
                 "untouched (use run_in_subprocess for a fresh client)",
                 RuntimeWarning, stacklevel=2)
             return False
+        pin_cpu_platform()
         env = os.environ
+    else:
+        pin_cpu_platform(env)
     env["XLA_FLAGS"] = _merged_flags(env.get("XLA_FLAGS", ""), n)
     return True
 
@@ -122,24 +146,26 @@ def fake_devices(n: int):
     """Context manager: ``n`` fake host devices for code run inside.
 
     Must enter before the first backend initialisation (launcher
-    entry points, subprocess scripts). The previous ``XLA_FLAGS`` value is
-    restored on exit — the *backend*, however, keeps whatever device count
-    it first initialised with; the restore only protects later child
-    processes from inheriting the forced flag.
+    entry points, subprocess scripts). The previous ``XLA_FLAGS`` and
+    ``JAX_PLATFORMS`` values are restored on exit — the *backend*,
+    however, keeps whatever device count and platform it first initialised
+    with; the restore only protects later child processes from inheriting
+    the forced settings.
 
     Yields True when the flag was applied, False when the backend was
     already up (in which case the environment is untouched).
     """
-    before = os.environ.get("XLA_FLAGS")
+    before = {k: os.environ.get(k) for k in ("XLA_FLAGS", "JAX_PLATFORMS")}
     applied = force_host_device_count(n)
     try:
         yield applied
     finally:
         if applied:
-            if before is None:
-                os.environ.pop("XLA_FLAGS", None)
-            else:
-                os.environ["XLA_FLAGS"] = before
+            for k, v in before.items():
+                if v is None:
+                    os.environ.pop(k, None)
+                else:
+                    os.environ[k] = v
 
 
 def run_in_subprocess(script: str, *, devices: int = 8, timeout: int = 600,
@@ -152,7 +178,9 @@ def run_in_subprocess(script: str, *, devices: int = 8, timeout: int = 600,
     applies no matter what this process's backend looks like — the pattern
     every multi-device CPU test uses. ``PYTHONPATH`` and the rest of the
     environment are inherited; the force flag is appended to (not
-    overwriting) any inherited ``XLA_FLAGS``.
+    overwriting) any inherited ``XLA_FLAGS``. The child runs with
+    ``JAX_PLATFORMS=cpu``: its devices are simulated on the host, and a
+    parent on an accelerator host already holds the chip.
 
     When ``marker`` is given, asserts it appears on the child's stdout and
     raises AssertionError carrying the stderr tail otherwise — the

@@ -1,12 +1,3 @@
-import importlib.util
-import os
-import sys
-
-# The image has no `hypothesis` and pip installs are off-limits: fall back
-# to the vendored shim in tests/_vendor (real library wins when present).
-if importlib.util.find_spec("hypothesis") is None:
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "_vendor"))
-
 import jax
 import numpy as np
 import pytest

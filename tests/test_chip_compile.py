@@ -1,0 +1,189 @@
+"""Compile for a described TPU v5e chip: the chip's compiler runs here,
+with no chip attached, so a kernel or a step the chip would refuse fails
+in tier-1 instead of on the chip.
+
+Every compile in the repository's test suite that targets the chip lives
+in this one file. The topology is described inside a module-scoped
+fixture (never at import): only one process at a time may load the TPU
+library, and pytest-xdist workers must all collect the same tests.
+Nothing here runs; ``tpu_custom_call`` in the HLO shows that a Pallas
+kernel was compiled by Mosaic rather than interpreted.
+"""
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, SingleDeviceSharding
+
+import repro
+from repro.configs.base import ShapeConfig
+
+GIB = 1 << 30
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "no TPU compiler"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep these compiles out of it
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(sharding, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _assert_mosaic(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8"])
+def test_paged_attention_compiles(one_chip, kv_int8):
+    from repro.kernels.paged_attention import paged_attention
+    b, h, g, d, ps, m, pages = 8, 16, 16, 64, 64, 32, 257
+    pool_dt = jnp.int8 if kv_int8 else jnp.bfloat16
+    args = [_sds(one_chip, (b, h, d), jnp.bfloat16),
+            _sds(one_chip, (pages, ps, g, d), pool_dt),
+            _sds(one_chip, (pages, ps, g, d), pool_dt),
+            _sds(one_chip, (b, m), jnp.int32),
+            _sds(one_chip, (b,), jnp.int32)]
+    if kv_int8:
+        scales = [_sds(one_chip, (pages, ps, g, 1))] * 2
+
+        def fn(q, kp, vp, table, lens, ks, vs):
+            return paged_attention(q, kp, vp, table, lens, k_scale=ks,
+                                   v_scale=vs, interpret=False)
+        args += scales
+    else:
+        def fn(q, kp, vp, table, lens):
+            return paged_attention(q, kp, vp, table, lens, interpret=False)
+    _assert_mosaic(_compile(fn, *args))
+
+
+def test_flash_attention_compiles(one_chip):
+    from repro.kernels.flash_attention import flash_attention
+    qkv = [_sds(one_chip, (16, 2048, 64), jnp.bfloat16)] * 3
+    _assert_mosaic(_compile(
+        lambda q, k, v: flash_attention(q, k, v, interpret=False), *qkv))
+
+
+@pytest.mark.parametrize("kernel", ["xfer_matmul", "quant_matmul"])
+def test_matmul_kernels_compile(one_chip, kernel):
+    from repro.kernels.quant_matmul import quant_matmul
+    from repro.kernels.xfer_matmul import xfer_matmul
+    x = _sds(one_chip, (256, 1024), jnp.bfloat16)
+    if kernel == "xfer_matmul":
+        w = _sds(one_chip, (1024, 2816), jnp.bfloat16)
+        c = _compile(lambda x, w: xfer_matmul(x, w, interpret=False), x, w)
+    else:
+        w = _sds(one_chip, (1024, 2816), jnp.int8)
+        s = _sds(one_chip, (1, 2816))
+        c = _compile(lambda x, w, s: quant_matmul(x, w, s, interpret=False),
+                     x, w, s)
+    _assert_mosaic(c)
+
+
+def test_rglru_scan_compiles(one_chip):
+    """recurrentgemma-2b's RG-LRU width (2560) over a 2048-token prefill."""
+    from repro.kernels.rglru_scan import rglru_scan
+    b, s, w = 2, 2048, 2560
+    _assert_mosaic(_compile(
+        lambda a, x, h: rglru_scan(a, x, h, interpret=False),
+        _sds(one_chip, (b, s, w)), _sds(one_chip, (b, s, w)),
+        _sds(one_chip, (b, w))))
+
+
+def test_mlstm_chunkwise_compiles(one_chip):
+    """xlstm-350m's mLSTM heads (4 heads of 512 = 2×d_model) over 2048."""
+    from repro.kernels.mlstm_kernel import mlstm_chunkwise
+    bh, s, d = 8, 2048, 512
+    qkv = [_sds(one_chip, (bh, s, d))] * 3
+    gates = [_sds(one_chip, (bh, s))] * 2
+    _assert_mosaic(_compile(
+        lambda q, k, v, i, f: mlstm_chunkwise(q, k, v, i, f,
+                                              interpret=False),
+        *qkv, *gates))
+
+
+def _serve_step_compiled(topo, arch, slots, max_len, *, paged=False):
+    """The engine's fused decode step (sampling form) for one described
+    chip, at the plan's shardings, with caches and state donated."""
+    from repro.models import registry as REG
+    from repro.serving import pages as PG
+    from repro.serving.sampler import GREEDY
+    from repro.serving.state import make_decode_state
+
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    plan = repro.plan(arch, ShapeConfig("decode", max_len, slots, "decode"),
+                      mesh=mesh)
+    ctx = plan.ctx(mesh)
+    dt = jnp.bfloat16
+    params = jax.eval_shape(
+        lambda: REG.init_params(arch, jax.random.PRNGKey(0), dt))
+    table_len = None
+    if paged:
+        table_len = PG.num_pages_per_slot(max_len, PG.DEFAULT_PAGE_SIZE)
+        caches = jax.eval_shape(lambda: PG.make_paged_caches(
+            arch, PG.default_kv_pages(slots, max_len, PG.DEFAULT_PAGE_SIZE),
+            PG.DEFAULT_PAGE_SIZE, dt))
+    else:
+        caches = jax.eval_shape(
+            lambda: REG.make_caches(arch, slots, max_len, dt))
+    state = jax.eval_shape(lambda: make_decode_state(slots, 0,
+                                                     table_len=table_len))
+    one = SingleDeviceSharding(topo.devices[0])
+    place = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), tree)
+    step = REG.build_serve_step(arch, ctx, sampling=GREEDY, paged=paged)
+    with mesh:
+        return jax.jit(step, donate_argnums=(1, 2)).lower(
+            place(params), place(caches), place(state)).compile()
+
+
+def test_qwen_serve_step_fits_one_chip(topo):
+    """qwen1.5-0.5b at published widths, 8 slots × 2048: the main path
+    of ``chip_smoke.py`` compiles for one v5e and fits its 16 GiB."""
+    compiled = _serve_step_compiled(topo, repro.get_arch("qwen1.5-0.5b"),
+                                    slots=8, max_len=2048)
+    mem = compiled.memory_analysis()
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert mem.alias_size_in_bytes > GIB  # the donated KV grid is aliased
+    assert live < 16 * GIB, f"{live / GIB:.2f} GiB"
+
+
+def test_paged_serve_step_emits_kernel_on_tpu(topo, monkeypatch):
+    """With the paged kernel selected, the platform choice in
+    ``kernels/ops.py`` compiles it by Mosaic on TPU (not interpreted)."""
+    from repro.kernels import ops
+    from repro.models import blocks
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(blocks, "_PAGED_ATTN_IMPL", "kernel")
+    arch = dataclasses.replace(repro.get_arch("qwen1.5-0.5b"), num_layers=2)
+    compiled = _serve_step_compiled(topo, arch, slots=8, max_len=2048,
+                                    paged=True)
+    _assert_mosaic(compiled)

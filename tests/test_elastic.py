@@ -1,10 +1,9 @@
 """runtime/elastic.py: grid selection, load controller, live migration.
 
-The property-based block uses hypothesis (the vendored shim in
-``tests/_vendor`` when the real package is absent; see conftest.py).
-The cross-mesh migration cells live in test_conformance.py (slow,
-subprocess, 8 fake devices); here the migration machinery is exercised
-end-to-end on the in-process device so tier-1 covers it.
+The property-based block uses hypothesis. The cross-mesh migration cells
+live in test_conformance.py (slow, subprocess, 8 fake devices); here the
+migration machinery is exercised end-to-end on the in-process device so
+tier-1 covers it.
 """
 import types
 

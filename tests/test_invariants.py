@@ -155,6 +155,7 @@ def test_force_host_device_count_env_dict():
     assert MF.force_host_device_count(8, env=env)
     assert "--xla_bar=7" in env["XLA_FLAGS"]
     assert "--xla_force_host_platform_device_count=8" in env["XLA_FLAGS"]
+    assert env["JAX_PLATFORMS"] == "cpu"  # simulated devices live on the host
     with pytest.raises(ValueError):
         MF.force_host_device_count(0, env=env)
 
@@ -206,3 +207,45 @@ def test_run_in_subprocess_forces_device_count():
         "import jax; print('DEVCOUNT', jax.device_count())",
         devices=2, timeout=300, marker="DEVCOUNT 2")
     assert r.returncode == 0
+
+
+def test_run_in_subprocess_pins_cpu_platform():
+    """The child simulates devices on the host: it must never reach for
+    the accelerator its parent already holds."""
+    r = MF.run_in_subprocess(
+        "import os, jax; print('PLATFORM', os.environ['JAX_PLATFORMS'], "
+        "jax.default_backend())",
+        devices=2, timeout=300, marker="PLATFORM cpu cpu",
+        extra_env={"JAX_PLATFORMS": "tpu"})
+    assert r.returncode == 0
+
+
+# ------------------------- compile cache -------------------------------
+
+@pytest.fixture()
+def cache_config():
+    import jax
+    before = jax.config.jax_compilation_cache_dir
+    yield jax.config
+    jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compile_cache_env_var_wins(cache_config, monkeypatch, tmp_path):
+    from repro.launch import compile_cache as CC
+    before = cache_config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert CC.enable_compile_cache() == str(tmp_path)
+    # JAX read the variable itself; the helper sets no other directory
+    assert cache_config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_checkout(cache_config, monkeypatch):
+    import pathlib
+
+    from repro.launch import compile_cache as CC
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    checkout = pathlib.Path(__file__).resolve().parents[1]
+    expected = str(checkout / ".jax_cache")
+    assert CC.compile_cache_dir() == expected
+    assert CC.enable_compile_cache() == expected
+    assert cache_config.jax_compilation_cache_dir == expected

@@ -1,7 +1,6 @@
 """Planner (Eq. 15 DSE) behaviour across cells and meshes.
 
-The property-based block at the bottom uses hypothesis (the vendored shim
-in tests/_vendor when the real library is absent — see conftest.py).
+The property-based block at the bottom uses hypothesis.
 """
 import dataclasses
 

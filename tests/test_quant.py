@@ -2,8 +2,7 @@
 hardening regressions (adamw clip, compression treedef, sampler top_k
 ties), cache/param structure, and planner-aware capacity.
 
-The property block uses hypothesis (the vendored shim in tests/_vendor
-when the real library is absent — see conftest.py).
+The property block uses hypothesis.
 """
 import jax
 import jax.numpy as jnp
