@@ -30,12 +30,14 @@ from typing import Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
 from repro.core.execution_plan import ExecutionPlan
 from repro.models import registry as REG
 from repro.quant import dequantize_params, quantize_params
+from repro.serving import spans as SP
 from repro.serving.config import PagingConfig, ServeConfig
 from repro.serving.pages import DEFAULT_PAGE_SIZE as PG_DEFAULT
 from repro.serving.sampler import GREEDY, SamplingParams
@@ -312,6 +314,14 @@ class ServingEngine:
         self.queue_depths = deque(maxlen=4096)
         self.retired_emits = deque(maxlen=4096)
         self.retired_active = deque(maxlen=4096)
+        # per step() call: seconds blocked reading step records (the
+        # loop's one host↔device sync), in Scheduler.admit, and in the
+        # serve step's dispatch; the same phases as the serve.* spans
+        self.record_wait_times = deque(maxlen=4096)
+        self.admit_times = deque(maxlen=4096)
+        self.dispatch_times = deque(maxlen=4096)
+        self._record_wait = 0.0
+        self._step_num = 0  # serve.step's step_num; never reset
 
     # ------------------------- queue / slot views -------------------------
     @property
@@ -343,46 +353,71 @@ class ServingEngine:
     def step(self):
         """One serving-loop iteration: retire the record(s) that fell out
         of the lookahead window, admit into the freed slots, dispatch the
-        next fused decode step."""
+        next fused decode step. Each phase runs under its ``serve.*``
+        profiler span (``serving/spans.py``) and its seconds go to the
+        per-step counters beside ``step_times``."""
         t0 = time.perf_counter()
-        self.queue_depths.append(len(self.queue))
-        emitted = 0
-        while len(self._pending) > self.lookahead:
-            emitted += self._retire_one()
-        # opportunistic early retire: a record whose device work already
-        # completed costs nothing to read now, and freeing its finished
-        # slots one step earlier avoids idle-slot decode steps under
-        # churn. Records still inside the lookahead window are only ever
-        # read when ready — the loop never blocks here.
-        while self._pending and _record_ready(self._pending[0]):
-            emitted += self._retire_one()
-        self.caches, self.state = self.scheduler.admit(
-            self.params, self.caches, self.state)
-        state, caches, record = self._serve_step(self.params, self.caches,
-                                                 self.state)
-        self.state, self.caches = state, caches
-        self._pending.append(record)
-        if self.lookahead == 0:
-            while self._pending:
-                emitted += self._retire_one()
+        self._record_wait = 0.0
+        with StepTraceAnnotation(SP.STEP, step_num=self._step_num):
+            self.queue_depths.append(len(self.queue))
+            emitted = 0
+            with TraceAnnotation(SP.RETIRE):
+                while len(self._pending) > self.lookahead:
+                    emitted += self._retire_one()
+                # opportunistic early retire: a record whose device work
+                # already completed costs nothing to read now, and freeing
+                # its finished slots one step earlier avoids idle-slot
+                # decode steps under churn. Records still inside the
+                # lookahead window are only ever read when ready — the
+                # loop never blocks here.
+                while self._pending and _record_ready(self._pending[0]):
+                    emitted += self._retire_one()
+            t1 = time.perf_counter()
+            with TraceAnnotation(SP.ADMIT):
+                self.caches, self.state = self.scheduler.admit(
+                    self.params, self.caches, self.state)
+            t2 = time.perf_counter()
+            with TraceAnnotation(SP.DISPATCH):
+                state, caches, record = self._serve_step(
+                    self.params, self.caches, self.state)
+            t3 = time.perf_counter()
+            self.state, self.caches = state, caches
+            self._pending.append(record)
+            if self.lookahead == 0:
+                with TraceAnnotation(SP.RETIRE):
+                    while self._pending:
+                        emitted += self._retire_one()
+        self._step_num += 1
         wall = time.perf_counter() - t0
         self.step_times.append(wall)
         self.step_token_counts.append(emitted)
+        self.record_wait_times.append(self._record_wait)
+        self.admit_times.append(t2 - t1)
+        self.dispatch_times.append(t3 - t2)
         if self.on_step is not None:
             self.on_step({"step": len(self.step_times) - 1,
-                          "wall_s": wall, "tokens": emitted})
+                          "wall_s": wall, "tokens": emitted,
+                          "record_wait_s": self._record_wait,
+                          "admit_s": t2 - t1, "dispatch_s": t3 - t2})
 
     def _retire_one(self) -> int:
         """Read one step record back (the only host↔device sync in the
         loop) and apply it: append emitted tokens, free finished slots.
+        The blocking read runs under ``serve.record_wait`` and its seconds
+        add to the step's ``record_wait`` counter; the read's end stamps
+        a request's ``first_token_at`` and ``finished_at``.
 
         Speculative steps return 2-D ``token``/``emit`` ([slots, k+1] —
         up to ``k+1`` commits per slot per step); the plain step's 1-D
         record is handled as the single-column case."""
         rec = self._pending.popleft()
-        token = np.asarray(rec["token"])
-        emit = np.asarray(rec["emit"])
-        finished = np.asarray(rec["finished"])
+        t = time.perf_counter()
+        with TraceAnnotation(SP.RECORD_WAIT):
+            token = np.asarray(rec["token"])
+            emit = np.asarray(rec["emit"])
+            finished = np.asarray(rec["finished"])
+        now = time.perf_counter()
+        self._record_wait += now - t
         if token.ndim == 1:
             token = token[:, None]
             emit = emit[:, None]
@@ -396,10 +431,12 @@ class ServingEngine:
                 continue
             for j in range(token.shape[1]):
                 if emit[slot, j]:
+                    if not req.out_tokens:
+                        req.first_token_at = now
                     req.out_tokens.append(int(token[slot, j]))
                     count += 1
             if finished[slot]:
-                req.finished_at = time.time()
+                req.finished_at = now
                 self.completed.append(req)
                 self.active[slot] = None
                 if self.paged:
@@ -645,18 +682,24 @@ class ServingEngine:
         self.queue_depths.clear()
         self.retired_emits.clear()
         self.retired_active.clear()
+        self.record_wait_times.clear()
+        self.admit_times.clear()
+        self.dispatch_times.clear()
         self.scheduler.reset_stats()
 
     def step_stats(self) -> Dict[str, float]:
         """p50/p95 decode-step wall time and aggregate token throughput.
 
-        ``queue_depth`` is the mean backlog observed at step dispatch;
-        ``accepted_tokens_mean`` is committed tokens per active slot-step
-        (1.0 for plain decoding, up to ``k+1`` under speculation — the
-        speedup lever). Speculative engines additionally report
-        ``draft_acceptance``: accepted / proposed draft tokens over the
-        currently-resident requests (device counters, zeroed at
-        admission)."""
+        ``record_wait_p50_ms`` / ``record_wait_max_ms`` are the time a
+        ``step()`` blocked reading step records (the host waiting on the
+        device); ``host_p50_ms`` is the median of each step's wall minus
+        that wait, the host's own work per step. ``queue_depth`` is the
+        mean backlog observed at step dispatch; ``accepted_tokens_mean``
+        is committed tokens per active slot-step (1.0 for plain decoding,
+        up to ``k+1`` under speculation — the speedup lever). Speculative
+        engines additionally report ``draft_acceptance``: accepted /
+        proposed draft tokens over the currently-resident requests
+        (device counters, zeroed at admission)."""
         from repro.core.stats import percentile
         ms = [t * 1e3 for t in self.step_times]
         total_s = sum(self.step_times)
@@ -664,11 +707,16 @@ class ServingEngine:
         qd = list(self.queue_depths)
         emits = sum(self.retired_emits)
         actives = sum(self.retired_active)
+        wait_ms = [t * 1e3 for t in self.record_wait_times]
         stats = {
             "steps": float(len(ms)),
             "step_p50_ms": percentile(ms, 50),
             "step_p95_ms": percentile(ms, 95),
             "step_mean_ms": (sum(ms) / len(ms)) if ms else 0.0,
+            "record_wait_p50_ms": percentile(wait_ms, 50),
+            "record_wait_max_ms": max(wait_ms, default=0.0),
+            "host_p50_ms": percentile([a - b for a, b in zip(ms, wait_ms)],
+                                      50),
             "tokens": float(toks),
             "tokens_per_s": toks / total_s if total_s > 0 else 0.0,
             "queue_depth": (sum(qd) / len(qd)) if qd else 0.0,

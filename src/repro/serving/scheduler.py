@@ -50,12 +50,14 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ArchConfig
 from repro.models import registry as REG
 from repro.quant import QuantConfig, dequantize_params
 from repro.serving import pages as PG
 from repro.serving import sampler as SMP
+from repro.serving import spans as SP
 from repro.serving.state import DecodeState, admit_rows
 
 PyTree = Any
@@ -101,7 +103,11 @@ class Request:
         self.patch_embeds = patch_embeds
         self._legacy_frames = frames
         self.out_tokens: List[int] = [] if out_tokens is None else out_tokens
+        # time.perf_counter() stamps: queued (submit), given a slot
+        # (admit), first token read back and finished (engine retire)
         self.submitted_at = submitted_at
+        self.admitted_at: Optional[float] = None
+        self.first_token_at: Optional[float] = None
         self.finished_at = finished_at
 
     @property
@@ -474,7 +480,7 @@ class Scheduler:
                 f"request {req.rid}: prompt {total} + max_new_tokens "
                 f"{req.max_new_tokens} exceeds max_len {self.max_len} "
                 f"(the slot's KV row holds prompt and decoded tokens)")
-        req.submitted_at = time.time()
+        req.submitted_at = time.perf_counter()
         self.queue.append(req)
 
     def _prefix_len(self, req: Request) -> int:
@@ -817,136 +823,142 @@ class Scheduler:
                 group = kept
                 if not group:
                     continue
-            t0 = time.perf_counter()
-            n = len(group)
-            width = bucket if kind == "lm_shared" else bucket - prefix
-            toks = np.zeros((n, width), np.int32)
-            lens = np.zeros((n,), np.int32)
-            slots_arr = np.zeros((n,), np.int32)
-            max_new = np.zeros((n,), np.int32)
-            rids_arr = np.zeros((n,), np.int32)
-            for i, (req, slot) in enumerate(group):
-                s = len(req.prompt)
-                if kind == "lm_shared":  # suffix tokens only; lens = total
-                    toks[i, :s - prefix] = req.prompt[prefix:]
-                    lens[i] = s
-                else:
-                    toks[i, :s] = req.prompt
-                    lens[i] = s + prefix if kind == "vlm" else s
-                slots_arr[i] = slot
-                max_new[i] = req.max_new_tokens
-                rids_arr[i] = req.rid
-            if self.worker is not None:
-                # disagg: run this group's prefill on the prefill slice;
-                # the outputs stream over asynchronously and splice in a
-                # later _integrate call. Slots are reserved host-side now
-                # (device-inactive until the splice lands).
-                frames = flens = patches = None
-                if kind == "encdec":
-                    frames, flens = self._marshal_frames(group)
-                elif kind == "vlm":
-                    patches = np.stack([req.patch_embeds for req, _ in group]
-                                       ).astype(np.float32)
-                outs = self.worker.dispatch(kind, bucket, prefix, toks=toks,
-                                            lens=lens, frames=frames,
-                                            flens=flens, patches=patches)
-                self.inflight.append(_Inflight(
-                    kind=kind, outs=outs, group=list(group), slots=slots_arr,
-                    lens=lens, max_new=max_new, rids=rids_arr, flens=flens,
-                    page_rows=(np.stack(page_rows_np) if self.paged
-                               else None),
-                    dispatch_wall=time.perf_counter() - t0))
+            with TraceAnnotation(SP.PREFILL, bucket=bucket, size=len(group),
+                                 rids=tuple(r.rid for r, _ in group)):
+                t0 = time.perf_counter()
+                n = len(group)
+                width = bucket if kind == "lm_shared" else bucket - prefix
+                toks = np.zeros((n, width), np.int32)
+                lens = np.zeros((n,), np.int32)
+                slots_arr = np.zeros((n,), np.int32)
+                max_new = np.zeros((n,), np.int32)
+                rids_arr = np.zeros((n,), np.int32)
                 for i, (req, slot) in enumerate(group):
-                    self.active[slot] = req
-                    admitted.add(req.rid)
-                    if self.paged:
-                        self.slot_pages[slot] = owned_list[i]
-                continue
-            slots_j = jnp.asarray(slots_arr)
-            lens_j = jnp.asarray(lens)
-            rids_j = jnp.asarray(rids_arr)
-            if kind == "lm_shared":
-                page_rows_j = jnp.asarray(np.stack(page_rows_np))
-                cow_pairs = [c for c in cows if c is not None]
-                if cow_pairs:
-                    dst = jnp.asarray([d for d, _ in cow_pairs], jnp.int32)
-                    src = jnp.asarray([s_ for _, s_ in cow_pairs], jnp.int32)
-                    caches = self._get_copy(len(cow_pairs))(caches, dst, src)
-                span = -(-prefix // self.page_size)
-                m_arr = jnp.full((n,), prefix, jnp.int32)
-                rows, logits = self._get_prefill_shared(bucket, n, span)(
-                    params, caches, page_rows_j[:, :span], m_arr,
-                    jnp.asarray(toks), lens_j)
-                caches = self._get_page_splice(n)(caches, rows, page_rows_j)
-                state = self._get_admit_paged(n)(
-                    state, slots_j, rids_j, logits, lens_j,
-                    jnp.asarray(max_new), page_rows_j)
-            elif kind == "encdec":
-                frames, flens = self._marshal_frames(group)
-                rows, logits, enc_out = self._get_prefill(
-                    kind, bucket, n)(params, jnp.asarray(frames),
-                                     jnp.asarray(flens), jnp.asarray(toks),
-                                     lens_j)
-                caches = self._get_splice(n)(caches, rows, slots_j)
-                state = self._get_admit(n, enc=True)(
-                    state, slots_j, rids_j, logits, lens_j,
-                    jnp.asarray(max_new), enc_out, jnp.asarray(flens))
-            else:
-                if kind == "vlm":
-                    patches = np.stack([req.patch_embeds for req, _ in group]
-                                       ).astype(np.float32)
-                    rows, logits = self._get_prefill(kind, bucket, n, prefix)(
-                        params, jnp.asarray(patches), jnp.asarray(toks),
-                        lens_j)
-                else:
-                    rows, logits = self._get_prefill(kind, bucket, n)(
-                        params, jnp.asarray(toks), lens_j)
-                if self.paged:
-                    # prefill compute stays dense and bucketed — paging
-                    # only redirects the splice target to the page pool
+                    s = len(req.prompt)
+                    if kind == "lm_shared":  # suffix tokens only; lens = total
+                        toks[i, :s - prefix] = req.prompt[prefix:]
+                        lens[i] = s
+                    else:
+                        toks[i, :s] = req.prompt
+                        lens[i] = s + prefix if kind == "vlm" else s
+                    slots_arr[i] = slot
+                    max_new[i] = req.max_new_tokens
+                    rids_arr[i] = req.rid
+                if self.worker is not None:
+                    # disagg: run this group's prefill on the prefill slice;
+                    # the outputs stream over asynchronously and splice in a
+                    # later _integrate call. Slots are reserved host-side now
+                    # (device-inactive until the splice lands).
+                    frames = flens = patches = None
+                    if kind == "encdec":
+                        frames, flens = self._marshal_frames(group)
+                    elif kind == "vlm":
+                        patches = np.stack([req.patch_embeds for req, _ in group]
+                                           ).astype(np.float32)
+                    outs = self.worker.dispatch(kind, bucket, prefix, toks=toks,
+                                                lens=lens, frames=frames,
+                                                flens=flens, patches=patches)
+                    self.inflight.append(_Inflight(
+                        kind=kind, outs=outs, group=list(group), slots=slots_arr,
+                        lens=lens, max_new=max_new, rids=rids_arr, flens=flens,
+                        page_rows=(np.stack(page_rows_np) if self.paged
+                                   else None),
+                        dispatch_wall=time.perf_counter() - t0))
+                    now = time.perf_counter()
+                    for i, (req, slot) in enumerate(group):
+                        self.active[slot] = req
+                        req.admitted_at = now
+                        admitted.add(req.rid)
+                        if self.paged:
+                            self.slot_pages[slot] = owned_list[i]
+                    continue
+                slots_j = jnp.asarray(slots_arr)
+                lens_j = jnp.asarray(lens)
+                rids_j = jnp.asarray(rids_arr)
+                if kind == "lm_shared":
                     page_rows_j = jnp.asarray(np.stack(page_rows_np))
-                    caches = self._get_page_splice(n)(caches, rows,
-                                                      page_rows_j)
+                    cow_pairs = [c for c in cows if c is not None]
+                    if cow_pairs:
+                        dst = jnp.asarray([d for d, _ in cow_pairs], jnp.int32)
+                        src = jnp.asarray([s_ for _, s_ in cow_pairs], jnp.int32)
+                        caches = self._get_copy(len(cow_pairs))(caches, dst, src)
+                    span = -(-prefix // self.page_size)
+                    m_arr = jnp.full((n,), prefix, jnp.int32)
+                    rows, logits = self._get_prefill_shared(bucket, n, span)(
+                        params, caches, page_rows_j[:, :span], m_arr,
+                        jnp.asarray(toks), lens_j)
+                    caches = self._get_page_splice(n)(caches, rows, page_rows_j)
                     state = self._get_admit_paged(n)(
                         state, slots_j, rids_j, logits, lens_j,
                         jnp.asarray(max_new), page_rows_j)
-                else:
+                elif kind == "encdec":
+                    frames, flens = self._marshal_frames(group)
+                    rows, logits, enc_out = self._get_prefill(
+                        kind, bucket, n)(params, jnp.asarray(frames),
+                                         jnp.asarray(flens), jnp.asarray(toks),
+                                         lens_j)
                     caches = self._get_splice(n)(caches, rows, slots_j)
-                    state = self._get_admit(n, enc=False)(
+                    state = self._get_admit(n, enc=True)(
                         state, slots_j, rids_j, logits, lens_j,
-                        jnp.asarray(max_new))
-            if self.draft is not None:
-                # draft prompt KV: full-prompt dense prefill at the
-                # group's full-length bucket (a prefix-shared group's
-                # target prefill is suffix-only, the draft's never is),
-                # spliced into the state's draft grid
-                dbucket = bucket_len(int(lens.max()), self.max_len,
-                                     min_bucket=MIN_BUCKET)
-                dtoks = np.zeros((n, dbucket), np.int32)
-                for i, (req, _) in enumerate(group):
-                    dtoks[i, :len(req.prompt)] = req.prompt
-                drows, _ = self.draft_factory.get("lm", dbucket, n)(
-                    dparams, jnp.asarray(dtoks), lens_j)
-                state = dataclasses.replace(
-                    state, draft_caches=self._get_draft_splice(n)(
-                        state.draft_caches, drows, slots_j))
-            for i, (req, slot) in enumerate(group):
-                self.active[slot] = req
-                admitted.add(req.rid)
-                if self.paged:
-                    self.slot_pages[slot] = owned_list[i]
-                    if self.registry is not None and req.patch_embeds is None:
-                        total = len(req.prompt)
-                        cover = -(-total // self.page_size)
-                        self.registry.register(
-                            np.asarray(req.prompt, np.int32),
-                            page_rows_np[i][:cover].tolist())
-            wall = time.perf_counter() - t0
-            self.prefill_dispatch_times.append(wall)
-            self.prefill_batch_sizes.append(n)
-            for req, _ in group:
-                self.prefill_times.append(wall / n)
-                self.prefill_prompt_lens.append(len(req.prompt))
+                        jnp.asarray(max_new), enc_out, jnp.asarray(flens))
+                else:
+                    if kind == "vlm":
+                        patches = np.stack([req.patch_embeds for req, _ in group]
+                                           ).astype(np.float32)
+                        rows, logits = self._get_prefill(kind, bucket, n, prefix)(
+                            params, jnp.asarray(patches), jnp.asarray(toks),
+                            lens_j)
+                    else:
+                        rows, logits = self._get_prefill(kind, bucket, n)(
+                            params, jnp.asarray(toks), lens_j)
+                    if self.paged:
+                        # prefill compute stays dense and bucketed — paging
+                        # only redirects the splice target to the page pool
+                        page_rows_j = jnp.asarray(np.stack(page_rows_np))
+                        caches = self._get_page_splice(n)(caches, rows,
+                                                          page_rows_j)
+                        state = self._get_admit_paged(n)(
+                            state, slots_j, rids_j, logits, lens_j,
+                            jnp.asarray(max_new), page_rows_j)
+                    else:
+                        caches = self._get_splice(n)(caches, rows, slots_j)
+                        state = self._get_admit(n, enc=False)(
+                            state, slots_j, rids_j, logits, lens_j,
+                            jnp.asarray(max_new))
+                if self.draft is not None:
+                    # draft prompt KV: full-prompt dense prefill at the
+                    # group's full-length bucket (a prefix-shared group's
+                    # target prefill is suffix-only, the draft's never is),
+                    # spliced into the state's draft grid
+                    dbucket = bucket_len(int(lens.max()), self.max_len,
+                                         min_bucket=MIN_BUCKET)
+                    dtoks = np.zeros((n, dbucket), np.int32)
+                    for i, (req, _) in enumerate(group):
+                        dtoks[i, :len(req.prompt)] = req.prompt
+                    drows, _ = self.draft_factory.get("lm", dbucket, n)(
+                        dparams, jnp.asarray(dtoks), lens_j)
+                    state = dataclasses.replace(
+                        state, draft_caches=self._get_draft_splice(n)(
+                            state.draft_caches, drows, slots_j))
+                now = time.perf_counter()
+                for i, (req, slot) in enumerate(group):
+                    self.active[slot] = req
+                    req.admitted_at = now
+                    admitted.add(req.rid)
+                    if self.paged:
+                        self.slot_pages[slot] = owned_list[i]
+                        if self.registry is not None and req.patch_embeds is None:
+                            total = len(req.prompt)
+                            cover = -(-total // self.page_size)
+                            self.registry.register(
+                                np.asarray(req.prompt, np.int32),
+                                page_rows_np[i][:cover].tolist())
+                wall = time.perf_counter() - t0
+                self.prefill_dispatch_times.append(wall)
+                self.prefill_batch_sizes.append(n)
+                for req, _ in group:
+                    self.prefill_times.append(wall / n)
+                    self.prefill_prompt_lens.append(len(req.prompt))
         leftover = [req for req, _ in pairs if req.rid not in admitted]
         if leftover:  # pool exhausted mid-wave: requeue in arrival order
             self.queue[:0] = leftover
