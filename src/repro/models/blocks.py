@@ -178,27 +178,36 @@ def _shared_prefix_attention(ctx, q, k, v, cache: dict, positions, seq_lens):
                                kv_valid, causal=True)
 
 
-def _cache_write(cache: dict, k_new, v_new, pos_new):
+def _cache_write(cache: dict, k_new, v_new, pos_new, layer=None):
     """Ring-buffer write of one token (decode step).
 
     Slot = position mod cache length, **per batch row**, so continuous
-    batching can hold requests at different positions in one grid.
+    batching can hold requests at different positions in one grid. Every
+    row is written, inert ones included.
+
+    With ``layer`` (a traced int32) the leaves are the whole stacked grid
+    ``[L, B, t, ...]`` that the layer scan carries, and only this layer's
+    token row is scattered in: XLA updates the carried buffer in place
+    instead of rewriting the layer's slab.
     """
-    t = cache["k"].shape[1]
+    t = cache["pos"].shape[-1]
+    rows = jnp.arange(pos_new.shape[0], dtype=jnp.int32)
     slot = (pos_new[:, 0] % t).astype(jnp.int32)  # [B]
-
-    def wr(c, u):
-        # per-row rank inside the vmap: start indices must cover c_.ndim
-        return jax.vmap(lambda c_, u_, i: jax.lax.dynamic_update_slice(
-            c_, u_.astype(c_.dtype), (i,) + (0,) * (c_.ndim - 1)))(c, u, slot)
-
+    at = (rows, slot) if layer is None else (layer, rows, slot)
     out = dict(cache)
     for name, u in _kv_leaves(cache, k_new, v_new):
-        out[name] = wr(cache[name], u)
-    out["pos"] = jax.vmap(lambda c, u, i: jax.lax.dynamic_update_slice(c, u, (i,)))(
-        cache["pos"], pos_new, slot)
-    out["count"] = cache["count"] + 1
+        out[name] = cache[name].at[at].set(u[:, 0].astype(cache[name].dtype))
+    out["pos"] = cache["pos"].at[at].set(pos_new[:, 0])
+    out["count"] = (cache["count"] + 1 if layer is None
+                    else cache["count"].at[layer].add(1))
     return out
+
+
+def _layer_view(cache: dict, layer) -> dict:
+    """Layer ``layer`` of a stacked grid: the ``[B, t, ...]`` leaves that
+    attention reads."""
+    return {name: jax.lax.dynamic_index_in_dim(x, layer, keepdims=False)
+            for name, x in cache.items() if name != "count"}
 
 
 def _cache_write_many(cache: dict, k_new, v_new, pos_new):
@@ -357,12 +366,18 @@ def attn_apply(arch: ArchConfig, p: dict, x: jax.Array, ctx=None, *,
                seq_lens: Optional[jax.Array] = None,
                page_table: Optional[jax.Array] = None,
                deterministic_router: bool = True,
-               append: bool = False
+               append: bool = False,
+               layer: Optional[jax.Array] = None
                ) -> Tuple[jax.Array, Optional[dict]]:
     """Self-attention + MLP/MoE block.
 
     full mode (cache is None or being filled): x is [B,S,D];
     decode mode (cache with count>0 and S==1): ring-buffer cache update.
+    With ``layer`` (single-token decode on the dense grid, from the layer
+    scan in ``models.lm.forward``) ``cache`` is the whole stacked grid the
+    scan carries: the token's K/V row is written in place at
+    ``[layer, row, pos % t]`` and attention reads layer ``layer`` of the
+    updated grid — the same values the per-layer write gives.
 
     ``append=True`` (speculative decoding) treats a filled cache as an
     append target for S≥1 fresh positions per row instead of a prefill
@@ -413,12 +428,13 @@ def attn_apply(arch: ArchConfig, p: dict, x: jax.Array, ctx=None, *,
                                        causal=causal, window=window,
                                        prefix_len=prefix_len)
     elif cache is not None and s == 1:
-        new_cache = _cache_write(cache, k, v, positions)
-        kv_valid = new_cache["pos"] >= 0
+        new_cache = _cache_write(cache, k, v, positions, layer)
+        view = new_cache if layer is None else _layer_view(new_cache, layer)
+        kv_valid = view["pos"] >= 0
         o = L.decode_attention_sharded(ctx, q,
-                                       _kv_read(new_cache, "k", q.dtype),
-                                       _kv_read(new_cache, "v", q.dtype),
-                                       positions, new_cache["pos"], kv_valid,
+                                       _kv_read(view, "k", q.dtype),
+                                       _kv_read(view, "v", q.dtype),
+                                       positions, view["pos"], kv_valid,
                                        causal=causal, window=window,
                                        prefix_len=prefix_len)
     else:

@@ -97,13 +97,26 @@ def _block_cache(kind: str, arch: ArchConfig, batch: int, length: int, dtype,
 
 def _block_apply(kind: str, arch: ArchConfig, p: PyTree, x, ctx, *,
                  positions, cache, prefix_len, moe: bool, seq_lens=None,
-                 page_table=None, append: bool = False):
+                 page_table=None, append: bool = False, layer=None):
+    """``layer``: ``cache`` is the body's stacked state carried by the
+    decode scan, and this block is layer ``layer`` of it."""
     if kind == "attn":
         win = arch.window if arch.family == "hybrid" else 0
         return B.attn_apply(arch, p, x, ctx, positions=positions, cache=cache,
                             window=win, prefix_len=prefix_len, moe=moe,
                             seq_lens=seq_lens, page_table=page_table,
-                            append=append)
+                            append=append, layer=layer)
+    if layer is not None:
+        # recurrent state is small: slice this layer's, write it back whole
+        x, state = _block_apply(
+            kind, arch, p, x, ctx, positions=positions, prefix_len=prefix_len,
+            moe=moe, seq_lens=seq_lens,
+            cache=jax.tree.map(
+                lambda c: jax.lax.dynamic_index_in_dim(c, layer, keepdims=False),
+                cache))
+        return x, jax.tree.map(
+            lambda c, u: jax.lax.dynamic_update_index_in_dim(c, u, layer, 0),
+            cache, state)
     if kind == "rglru":
         return R.rglru_apply(arch, p, x, ctx, state=cache, seq_lens=seq_lens)
     if kind == "mlstm":
@@ -251,6 +264,13 @@ def forward(arch: ArchConfig, params: Dict, tokens: jax.Array,
             append: bool = False) -> Tuple[jax.Array, Optional[Dict]]:
     """Returns (hidden [B,S,D] after final norm, updated caches or None).
 
+    Single-token decode on the dense grid (``caches`` given, S == 1, not
+    ``append``, no ``page_table``) carries the body's stacked grid through
+    the layer scan: each layer scatters its new K/V row into the carried
+    grid in place and attends over its layer of it (recurrent state is
+    sliced and written back whole). Prefill, append and paged forwards
+    scan the grid as per-layer inputs and outputs.
+
     ``append=True`` (speculative decoding): ``caches`` is a *filled*
     grid and the S fresh tokens per row are scattered at ``positions``
     instead of re-filling from scratch — attention-only archs, see
@@ -288,14 +308,15 @@ def forward(arch: ArchConfig, params: Dict, tokens: jax.Array,
 
     new_caches: Dict[str, Any] = {}
 
-    def apply_one(kind, p, h, cache, moe_block=None):
+    def apply_one(kind, p, h, cache, moe_block=None, layer=None):
         use_moe = (moe and kind == "attn") if moe_block is None else moe_block
 
         def fn(p_, h_, cache_):
             return _block_apply(kind, arch, p_, h_, ctx, positions=positions,
                                 prefix_len=prefix_len, moe=use_moe,
                                 cache=cache_, seq_lens=seq_lens,
-                                page_table=page_table, append=append)
+                                page_table=page_table, append=append,
+                                layer=layer)
         if remat:
             fn = jax.checkpoint(fn, policy=_REMAT_POLICY)
         return fn(p, h, cache)
@@ -321,6 +342,22 @@ def forward(arch: ArchConfig, params: Dict, tokens: jax.Array,
         if caches is None:
             x = scan_layers(lambda p, h: pattern_body(p, h)[0], params["body"], x,
                             ctx=ctx, specs=body_dims_unstacked(arch))
+        elif s == 1 and not append and page_table is None:
+            # decode: the stacked grid is carried, never sliced out and
+            # stacked back, so each step writes one token per layer
+            def body(carry, xs):
+                h, grid = carry
+                p_rep, i = xs
+                grid = dict(grid)
+                for j, kind in enumerate(pat):
+                    key = f"b{j}_{kind}"
+                    h, grid[key] = apply_one(kind, p_rep[key], h, grid[key],
+                                             layer=i)
+                return (h, grid), None
+
+            (x, new_caches["body"]), _ = jax.lax.scan(
+                body, (x, caches["body"]),
+                (params["body"], jnp.arange(repeats, dtype=jnp.int32)))
         else:
             def body(h, xs):
                 p_rep, cache_rep = xs

@@ -11,6 +11,7 @@ kernel was compiled by Mosaic rather than interpreted.
 """
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -174,6 +175,49 @@ def test_qwen_serve_step_fits_one_chip(topo):
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert mem.alias_size_in_bytes > GIB  # the donated KV grid is aliased
     assert live < 16 * GIB, f"{live / GIB:.2f} GiB"
+
+
+def _grid_shaped_ops(hlo: str, shape: str):
+    """(opcode, fusion kind) of every instruction, in any computation,
+    whose result has exactly ``shape``; operands and plumbing left out."""
+    inst = re.compile(r"^\s*(?:ROOT )?%\S+ = " + re.escape(shape)
+                      + r"\{[^}]*\} ([\w-]+)\(")
+    ops = []
+    for line in hlo.splitlines():
+        m = inst.match(line)
+        if m and m.group(1) not in ("parameter", "get-tuple-element",
+                                    "tuple", "bitcast"):
+            kind = re.search(r"kind=(k\w+)", line)
+            ops.append((m.group(1), kind.group(1) if kind else None))
+    return ops
+
+
+def test_yi9b_serve_step_writes_kv_grid_in_place(topo):
+    """Yi-9B at published widths (d_model 4096, 32/4 heads of 128, d_ff
+    11008, vocabulary 64000), 16 layers, 32 slots × 4096: the benchmark's
+    ``yi-9b-l16`` serve step. Decode carries the stacked K/V grid
+    ``bf16[16,32,4096,4,128]`` (2 × 2.0 GiB) through the layer scan and
+    scatters one token row per layer into it.
+
+    Before (grid scanned as per-layer inputs and outputs): 4.51 GiB of
+    temporaries, and grid-sized ``copy`` ×2, ``AllocateBuffer`` ×2 and
+    ``dynamic-update-slice`` loop fusions ×2. After: 0.126 GiB, and the
+    only grid-sized results are the two in-place scatter fusions. The
+    bound, one layer's K and V slabs (0.25 GiB), also refuses carrying the
+    grid but writing each layer's whole slab back (0.38 GiB)."""
+    arch = dataclasses.replace(repro.get_arch("yi-9b"), num_layers=16)
+    slots, max_len = 32, 4096
+    compiled = _serve_step_compiled(topo, arch, slots=slots, max_len=max_len)
+    mem = compiled.memory_analysis()
+    shape = (arch.num_layers, slots, max_len, arch.num_kv_heads, arch.head_dim)
+    grid_bytes = 2 * int(np.prod(shape)) * 2  # K and V, bf16
+    assert mem.alias_size_in_bytes >= grid_bytes  # the donated grid
+    assert mem.temp_size_in_bytes < grid_bytes / arch.num_layers, (
+        f"temp {mem.temp_size_in_bytes / GIB:.3f} GiB")
+    ops = _grid_shaped_ops(compiled.as_text(),
+                           "bf16[" + ",".join(map(str, shape)) + "]")
+    assert ops and all(op == "scatter" or (op == "fusion" and kind == "kCustom")
+                       for op, kind in ops), ops
 
 
 def test_paged_serve_step_emits_kernel_on_tpu(topo, monkeypatch):
