@@ -520,3 +520,28 @@ def analyze(hlo: str) -> Cost:
         return c
 
     return comp_cost(entry) if entry else Cost()
+
+
+def collective_stats(compiled) -> Dict[str, Dict[str, float]]:
+    """Collectives of one execution of a compiled program, by kind:
+    ``{"all-reduce": {"count": n, "wire_bytes": b, "max_bytes": m}, ...}``.
+
+    ``compiled`` is a ``jax.stages.Compiled``. Every loop body counts its
+    trip count times (``analyze``), so a layer scan's exchanges count once
+    per layer; ``wire_bytes`` is ring-counted per device; ``max_bytes`` is
+    the largest operand or result of one collective of that kind. A
+    program with no collective gives ``{}``."""
+    text = compiled.as_text()
+    largest: Dict[str, float] = defaultdict(float)
+    for comp in _parse_computations(text).values():
+        for ins in comp.instrs:
+            kind = ins.opcode.replace("-start", "").replace("-done", "")
+            if kind in _COLLECTIVES and not ins.opcode.endswith("-done"):
+                sizes = [_shape_elems_bytes(t)[1] for t in
+                         [ins.type_str] + [comp.types.get(n, "")
+                                           for n in ins.operands]]
+                largest[kind] = max(largest[kind], *sizes)
+    return {kind: {"count": int(round(v["count"])),
+                   "wire_bytes": float(v["wire_bytes"]),
+                   "max_bytes": float(largest[kind])}
+            for kind, v in sorted(analyze(text).coll.items()) if v["count"]}

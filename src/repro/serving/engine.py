@@ -203,8 +203,19 @@ class ServingEngine:
             self.page_size = config.paging.page_size
             self.kv_pages = config.paging.kv_pages
             table_len = None
-            self.caches = REG.make_caches(arch, slots, max_len, dtype,
-                                          kv_quant=self.quant.quant_kv)
+
+            def grid():
+                return REG.make_caches(arch, slots, max_len, dtype,
+                                       kv_quant=self.quant.quant_kv)
+            if self.plan is None:
+                self.caches = grid()
+            else:
+                # made in the plan's shardings: a grid larger than one
+                # device's memory (Yi-9B whole at 128 × 2048: 25.8 GB)
+                # never lands whole on the first device
+                self.caches = mesh_jit(self.mesh, grid, out_shardings=(
+                    self.plan.cache_shardings(jax.eval_shape(grid),
+                                              self.mesh)))()
         # the resolved surface (page geometry made concrete) — what
         # `engine.config` exposes
         self.config: ServeConfig = _dc.replace(
@@ -240,10 +251,6 @@ class ServingEngine:
                 # shardings don't apply: every device holds the whole pool
                 # (gathered reads are resharded on the fly)
                 self.caches = jax.device_put(self.caches, replicated)
-            else:
-                self.caches = jax.device_put(
-                    self.caches,
-                    self.plan.cache_shardings(self.caches, self.mesh))
             self.state = jax.device_put(
                 self.state, tree_shardings(self.plan.ctx(self.mesh),
                                            self.state,
@@ -322,6 +329,7 @@ class ServingEngine:
         self.dispatch_times = deque(maxlen=4096)
         self._record_wait = 0.0
         self._step_num = 0  # serve.step's step_num; never reset
+        self._collectives: Optional[Dict[str, Dict[str, float]]] = None
 
     # ------------------------- queue / slot views -------------------------
     @property
@@ -442,6 +450,29 @@ class ServingEngine:
                 if self.paged:
                     self.scheduler.release_slot(slot)
         return count
+
+    def collective_stats(self) -> Dict[str, Dict[str, float]]:
+        """The fused serve step's collectives per execution, by kind:
+        ``{"all-reduce": {"count": n, "wire_bytes": b, "max_bytes": m},
+        ...}``, the layer scan's trip count applied
+        (``launch.hlo_analysis.collective_stats``).
+
+        Compiled on the first call from the engine's own jitted step at
+        the live arguments' shapes and shardings, then cached (``migrate``
+        drops the cache); neither ``step()`` nor construction calls it.
+        ``{}`` on one device."""
+        if self._collectives is None:
+            if self.mesh is None or self.mesh.devices.size == 1:
+                self._collectives = {}
+            else:
+                from repro.launch.hlo_analysis import collective_stats
+                args = jax.tree.map(
+                    lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                                   sharding=x.sharding),
+                    (self.params, self.caches, self.state))
+                self._collectives = collective_stats(
+                    self._serve_step.lower(*args).compile())
+        return {k: dict(v) for k, v in self._collectives.items()}
 
     def _flush(self) -> int:
         count = 0
@@ -596,6 +627,7 @@ class ServingEngine:
                            inner_step(dequantize_params(params), caches,
                                       state))
         self._serve_step = mesh_jit(new_mesh, step_fn, donate_argnums=(1, 2))
+        self._collectives = None
         self.scheduler.rebind_mesh(new_mesh)
         from_axes = tuple(self.plan.mesh_axes)
         self.plan = new_plan

@@ -130,15 +130,19 @@ def test_mlstm_chunkwise_compiles(one_chip):
         *qkv, *gates))
 
 
-def _serve_step_compiled(topo, arch, slots, max_len, *, paged=False):
-    """The engine's fused decode step (sampling form) for one described
-    chip, at the plan's shardings, with caches and state donated."""
+def _serve_step_compiled(topo, arch, slots, max_len, *, paged=False,
+                         grid=(1, 1)):
+    """The engine's fused decode step (sampling form) for described chips
+    on a ``grid`` (data, model) mesh, with params, caches and state at
+    the plan's shardings, caches and state donated."""
+    from repro.core.xfer import tree_shardings
     from repro.models import registry as REG
     from repro.serving import pages as PG
     from repro.serving.sampler import GREEDY
-    from repro.serving.state import make_decode_state
+    from repro.serving.state import decode_state_dims, make_decode_state
 
-    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    n = grid[0] * grid[1]
+    mesh = Mesh(np.array(topo.devices[:n]).reshape(grid), ("data", "model"))
     plan = repro.plan(arch, ShapeConfig("decode", max_len, slots, "decode"),
                       mesh=mesh)
     ctx = plan.ctx(mesh)
@@ -151,18 +155,25 @@ def _serve_step_compiled(topo, arch, slots, max_len, *, paged=False):
         caches = jax.eval_shape(lambda: PG.make_paged_caches(
             arch, PG.default_kv_pages(slots, max_len, PG.DEFAULT_PAGE_SIZE),
             PG.DEFAULT_PAGE_SIZE, dt))
+        cache_sh = jax.tree.map(
+            lambda _: jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec()),
+            caches)
     else:
         caches = jax.eval_shape(
             lambda: REG.make_caches(arch, slots, max_len, dt))
+        cache_sh = plan.cache_shardings(caches, mesh)
     state = jax.eval_shape(lambda: make_decode_state(slots, 0,
                                                      table_len=table_len))
-    one = SingleDeviceSharding(topo.devices[0])
-    place = lambda tree: jax.tree.map(  # noqa: E731
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), tree)
+    place = lambda tree, sh: jax.tree.map(  # noqa: E731
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        tree, sh)
     step = REG.build_serve_step(arch, ctx, sampling=GREEDY, paged=paged)
     with mesh:
         return jax.jit(step, donate_argnums=(1, 2)).lower(
-            place(params), place(caches), place(state)).compile()
+            place(params, plan.param_shardings(params, mesh)),
+            place(caches, cache_sh),
+            place(state, tree_shardings(ctx, state, decode_state_dims(
+                paged=paged)))).compile()
 
 
 def test_qwen_serve_step_fits_one_chip(topo):
@@ -218,6 +229,41 @@ def test_yi9b_serve_step_writes_kv_grid_in_place(topo):
                            "bf16[" + ",".join(map(str, shape)) + "]")
     assert ops and all(op == "scatter" or (op == "fusion" and kind == "kCustom")
                        for op, kind in ops), ops
+
+
+def test_yi9b_whole_serve_step_fits_a_2x2_host(topo):
+    """Yi-9B whole (48 layers at published widths), 128 slots × 2048: the
+    benchmark's ``yi-9b-2x2`` serve step on a described v5e 2x2, at the
+    plan's shardings (4-way tensor parallel over the data × model grid
+    ``repro.plan`` fits to four devices, the K/V grid split by sequence).
+
+    Today: 10.87 GB of arguments per device (the donated grid 6.46 GB of
+    them) and 0.068 GB of temporaries. Per layer, 5 all-reduces (the two
+    row-parallel outputs ``bf16[128,1,4096]``; flash-decoding's merge,
+    ``f32[128,1,32,128]`` and two ``f32[128,1,32,1]``) and 6 all-gathers
+    (the new K/V rows ``bf16[128,4,128]`` ×4, the queries
+    ``bf16[128,1,32,128]``, one ``bf16[128,512]``): 241 all-reduces and
+    290 all-gathers per step with the embedding's and the state's. Every
+    one is activation-sized; the 16 MB bound refuses any gather of a
+    weight or of the grid, and the bound of 12 per layer makes a change
+    that adds an exchange to every layer say so here."""
+    from repro.launch.hlo_analysis import collective_stats
+    arch = repro.get_arch("yi-9b")
+    assert arch.num_layers == 48
+    slots, max_len, chips = 128, 2048, 4
+    compiled = _serve_step_compiled(topo, arch, slots=slots, max_len=max_len,
+                                    grid=(2, 2))
+    mem = compiled.memory_analysis()
+    per_device = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert per_device < 15.75e9, f"{per_device / 1e9:.2f} GB"
+    # one layer's K and V slabs on one device
+    slab = 2 * slots * max_len * arch.num_kv_heads * arch.head_dim * 2 // chips
+    assert mem.temp_size_in_bytes < slab, (
+        f"temp {mem.temp_size_in_bytes / 1e9:.3f} GB")
+    stats = collective_stats(compiled)
+    assert max(v["max_bytes"] for v in stats.values()) <= 16e6, stats
+    per_layer = sum(v["count"] for v in stats.values()) / arch.num_layers
+    assert 0 < per_layer <= 12, stats
 
 
 def test_paged_serve_step_emits_kernel_on_tpu(topo, monkeypatch):
