@@ -26,21 +26,25 @@ from repro.quant import quantize_kv
 
 def make_kv_cache(arch: ArchConfig, batch: int, length: int, dtype=jnp.bfloat16,
                   window: int = 0, kv_quant: bool = False) -> dict:
-    """``kv_quant=True`` stores K/V as int8 with per-token f32 scale
-    leaves (``k_scale``/``v_scale`` ``[B, t, G, 1]``, one scale per token
+    """K/V are stored head-major, ``[B, G, t, D]``: the order the
+    attention dots contract in (``layers._attend_block``), so decode reads
+    a layer's slab where it lies. ``pos`` is ``[B, t]``.
+
+    ``kv_quant=True`` stores K/V as int8 with per-token f32 scale
+    leaves (``k_scale``/``v_scale`` ``[B, G, t, 1]``, one scale per token
     per KV group). The scales are ordinary cache leaves: they splice,
     page and shard structurally alongside the payload they describe."""
     t = min(length, window) if window else length
     g, d = arch.num_kv_heads, arch.head_dim
     cache = {
-        "k": jnp.zeros((batch, t, g, d), jnp.int8 if kv_quant else dtype),
-        "v": jnp.zeros((batch, t, g, d), jnp.int8 if kv_quant else dtype),
+        "k": jnp.zeros((batch, g, t, d), jnp.int8 if kv_quant else dtype),
+        "v": jnp.zeros((batch, g, t, d), jnp.int8 if kv_quant else dtype),
         "pos": jnp.full((batch, t), -1, jnp.int32),  # -1 = invalid slot
         "count": jnp.zeros((), jnp.int32),
     }
     if kv_quant:
-        cache["k_scale"] = jnp.zeros((batch, t, g, 1), jnp.float32)
-        cache["v_scale"] = jnp.zeros((batch, t, g, 1), jnp.float32)
+        cache["k_scale"] = jnp.zeros((batch, g, t, 1), jnp.float32)
+        cache["v_scale"] = jnp.zeros((batch, g, t, 1), jnp.float32)
     return cache
 
 
@@ -51,9 +55,10 @@ def kv_quantized(cache: dict) -> bool:
 def _kv_leaves(cache: dict, k: jax.Array, v: jax.Array):
     """Fresh fp K/V → the cache's storage leaves: ``[(name, value)]``
     pairs matching the dict layout (int8 payload + per-token scales for
-    quantised caches). Per-token quantisation commutes with any
-    gather/slice/pad along the length axis, so fill paths can quantise
-    first and reuse their fp indexing untouched."""
+    quantised caches), in the order ``k``/``v`` come in. Per-token
+    quantisation commutes with any gather/slice/pad/transpose of the
+    token and head axes, so fill paths can quantise first and reuse their
+    fp indexing untouched."""
     if "k_scale" not in cache:
         return [("k", k.astype(cache["k"].dtype)),
                 ("v", v.astype(cache["v"].dtype))]
@@ -95,10 +100,10 @@ def _paged_decode_attention(ctx, q, k, v, cache: dict,
     slot's frontier page(s), then attend over the slot's page list.
 
     The gather path materialises ``[B, M·ps, G, D]`` keys through the
-    page table and runs the *same* attention the dense grid runs —
-    positions beyond the frontier map to the null page or to a not-yet-
-    written tail and are masked exactly like the dense grid's stale
-    ``pos=-1`` entries, so the two layouts are bit-identical when
+    page table, turns them head-major and runs the *same* attention the
+    dense grid runs — positions beyond the frontier map to the null page
+    or to a not-yet-written tail and are masked exactly like the dense
+    grid's stale ``pos=-1`` entries, so the two layouts are bit-identical when
     ``page_size`` divides ``max_len`` (equal kv extent per shard).
 
     S>1 is the speculative verify: positions are the contiguous range
@@ -145,9 +150,13 @@ def _paged_decode_attention(ctx, q, k, v, cache: dict,
         if quant:
             s_ = new_cache[f"{name}s"][page_table].reshape(b, t, *x.shape[2:-1] + (1,))
             x = (x.astype(jnp.float32) * s_).astype(q.dtype)
-        return x
+        return x.swapaxes(1, 2)  # [B, G, t, D], the dense grid's order
 
-    kf, vf = flat("kp"), flat("vp")
+    # materialised head-major like a layer of the dense grid: a transpose
+    # left for the compiler to fold into the attention dots reorders
+    # their accumulation, and the two layouts would no longer agree
+    # bit for bit
+    kf, vf = jax.lax.optimization_barrier((flat("kp"), flat("vp")))
     kv_pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[None], (b, t))
     kv_valid = kv_pos <= pos[:, -1][:, None]
     o = L.decode_attention_sharded(ctx, q, kf, vf, positions, kv_pos,
@@ -162,12 +171,13 @@ def _shared_prefix_attention(ctx, q, k, v, cache: dict, positions, seq_lens):
     kv set per query is identical to a full-prompt prefill — padding
     (the gathered region's tail and the suffix bucket's tail) is masked
     to exact zeros, so the suffix hidden states match the full prefill
-    bit-for-bit."""
+    bit-for-bit. ``k``/``v`` are the suffix's, head-major; the gathered
+    prefix is token-major, as the pool stores it."""
     b, s = q.shape[0], q.shape[1]
     pre_k, pre_v, pre_len = cache["pre_k"], cache["pre_v"], cache["pre_len"]
     lp = pre_k.shape[1]
-    k_cat = jnp.concatenate([pre_k.astype(k.dtype), k], axis=1)
-    v_cat = jnp.concatenate([pre_v.astype(v.dtype), v], axis=1)
+    k_cat = jnp.concatenate([pre_k.astype(k.dtype).swapaxes(1, 2), k], axis=2)
+    v_cat = jnp.concatenate([pre_v.astype(v.dtype).swapaxes(1, 2), v], axis=2)
     pre_pos = jnp.broadcast_to(jnp.arange(lp, dtype=jnp.int32)[None], (b, lp))
     kv_pos = jnp.concatenate([pre_pos, positions], axis=1)
     pre_valid = pre_pos < pre_len[:, None]
@@ -179,33 +189,57 @@ def _shared_prefix_attention(ctx, q, k, v, cache: dict, positions, seq_lens):
 
 
 def _cache_write(cache: dict, k_new, v_new, pos_new, layer=None):
-    """Ring-buffer write of one token (decode step).
+    """Ring-buffer write of one token (decode step). ``k_new``/``v_new``
+    are the fresh ``[B, 1, G, D]`` rows.
 
     Slot = position mod cache length, **per batch row**, so continuous
     batching can hold requests at different positions in one grid. Every
     row is written, inert ones included.
 
     With ``layer`` (a traced int32) the leaves are the whole stacked grid
-    ``[L, B, t, ...]`` that the layer scan carries, and only this layer's
-    token row is scattered in: XLA updates the carried buffer in place
-    instead of rewriting the layer's slab.
+    ``[L, B, G, t, ...]`` that the layer scan carries, and only this
+    layer's token row is scattered in: XLA updates the carried buffer in
+    place instead of rewriting the layer's slab.
+
+    The K/V head is a scatter index, so each update window is one head's
+    ``D`` values: a ``[G, D]`` window across the head-major grid's
+    ``(G, t)`` axes would make the compiler lay the grid out token-major
+    again, and relayout every layer's slab for the attention dots.
     """
+    b, _, g, _ = k_new.shape
     t = cache["pos"].shape[-1]
-    rows = jnp.arange(pos_new.shape[0], dtype=jnp.int32)
+    rows = jnp.arange(b, dtype=jnp.int32)
     slot = (pos_new[:, 0] % t).astype(jnp.int32)  # [B]
-    at = (rows, slot) if layer is None else (layer, rows, slot)
+    at = (rows[:, None], jnp.arange(g, dtype=jnp.int32)[None, :],
+          slot[:, None])  # [B, G]
+    at_pos = (rows, slot)
+    if layer is not None:
+        at, at_pos = (layer,) + at, (layer,) + at_pos
     out = dict(cache)
     for name, u in _kv_leaves(cache, k_new, v_new):
         out[name] = cache[name].at[at].set(u[:, 0].astype(cache[name].dtype))
-    out["pos"] = cache["pos"].at[at].set(pos_new[:, 0])
+    out["pos"] = cache["pos"].at[at_pos].set(pos_new[:, 0])
     out["count"] = (cache["count"] + 1 if layer is None
                     else cache["count"].at[layer].add(1))
     return out
 
 
+def _grid_rows(ctx, k, v):
+    """Fresh K/V rows ``[B, S, G, D]`` in the sharding of the grid they
+    are written to (``lm.cache_dims``): whole on every chip when the grid
+    is split by sequence, as the explicit-SPMD plans split it. Left in
+    the projection's split by head, the rows make the partitioner gather
+    the scatter's indices instead: two more exchanges per layer."""
+    from repro.core.xfer import explicit_spmd_enabled
+    if ctx is None or not explicit_spmd_enabled():
+        return k, v
+    return (ctx.constrain(k, "batch", None, None, None),
+            ctx.constrain(v, "batch", None, None, None))
+
+
 def _layer_view(cache: dict, layer) -> dict:
-    """Layer ``layer`` of a stacked grid: the ``[B, t, ...]`` leaves that
-    attention reads."""
+    """Layer ``layer`` of a stacked grid: the ``[B, G, t, ·]`` and
+    ``[B, t]`` leaves that attention reads."""
     return {name: jax.lax.dynamic_index_in_dim(x, layer, keepdims=False)
             for name, x in cache.items() if name != "count"}
 
@@ -220,15 +254,18 @@ def _cache_write_many(cache: dict, k_new, v_new, pos_new):
     accept frontier always store a position greater than any future
     query position below them, and every verify rewrites the full
     ``p..p+k`` range before the in-step read, so stale data is never
-    attended.
+    attended. ``k_new``/``v_new`` are ``[B, S, G, D]``; the K/V head is
+    a scatter index, as in :func:`_cache_write`.
     """
     b, s = pos_new.shape
-    t = cache["k"].shape[1]
+    g = k_new.shape[2]
+    t = cache["pos"].shape[-1]
     rows = jnp.arange(b, dtype=jnp.int32)[:, None]
     slot = jnp.where(pos_new >= 0, pos_new, t)  # negative → dropped too
+    heads = jnp.arange(g, dtype=jnp.int32)[None, None, :]
     out = dict(cache)
     for name, u in _kv_leaves(cache, k_new, v_new):
-        out[name] = cache[name].at[rows, slot].set(
+        out[name] = cache[name].at[rows[..., None], heads, slot[..., None]].set(
             u.astype(cache[name].dtype), mode="drop")
     out["pos"] = cache["pos"].at[rows, slot].set(pos_new, mode="drop")
     out["count"] = cache["count"] + s
@@ -341,8 +378,9 @@ def _ring_exact_fill(cache: dict, k, v, seq_lens: jax.Array, s: int) -> dict:
     padded sequence instead, which evicts real context whenever the
     prompt is shorter than the bucket; per-row gather by true length
     makes the fill identical for every padded length ≥ the prompt.
+    ``k``/``v`` are head-major ``[B, G, s, D]``.
     """
-    t = cache["k"].shape[1]
+    t = cache["pos"].shape[-1]
     ring = jnp.arange(t)[None, :]  # [1, t]
     last = seq_lens[:, None] - 1
     pos = last - jnp.mod(last - ring, t)  # [B, t], pos ≡ ring (mod t)
@@ -351,7 +389,7 @@ def _ring_exact_fill(cache: dict, k, v, seq_lens: jax.Array, s: int) -> dict:
     out = dict(cache)
     for name, u in _kv_leaves(cache, k, v):
         out[name] = jnp.take_along_axis(
-            u, idx[:, :, None, None], axis=1).astype(cache[name].dtype)
+            u, idx[:, None, :, None], axis=2).astype(cache[name].dtype)
     out["pos"] = jnp.where(valid, pos, -1)
     out["count"] = jnp.asarray(s, jnp.int32)
     return out
@@ -376,8 +414,13 @@ def attn_apply(arch: ArchConfig, p: dict, x: jax.Array, ctx=None, *,
     With ``layer`` (single-token decode on the dense grid, from the layer
     scan in ``models.lm.forward``) ``cache`` is the whole stacked grid the
     scan carries: the token's K/V row is written in place at
-    ``[layer, row, pos % t]`` and attention reads layer ``layer`` of the
-    updated grid — the same values the per-layer write gives.
+    ``[layer, row, :, pos % t]`` and attention reads layer ``layer`` of
+    the updated grid — the same values the per-layer write gives.
+
+    The grid is head-major (:func:`make_kv_cache`), and so is every K/V
+    operand of the attention core; freshly projected K/V (prefill, the
+    shared-prefix suffix) is transposed once before it is attended or
+    stored.
 
     ``append=True`` (speculative decoding) treats a filled cache as an
     append target for S≥1 fresh positions per row instead of a prefill
@@ -415,11 +458,14 @@ def attn_apply(arch: ArchConfig, p: dict, x: jax.Array, ctx=None, *,
         o, new_cache = _paged_decode_attention(ctx, q, k, v, cache,
                                                page_table, positions, causal)
     elif cache is not None and "pre_k" in cache:
-        o = _shared_prefix_attention(ctx, q, k, v, cache, positions, seq_lens)
-        new_cache = {"k": k, "v": v, "pos": positions,
+        kh, vh = k.swapaxes(1, 2), v.swapaxes(1, 2)
+        o = _shared_prefix_attention(ctx, q, kh, vh, cache, positions,
+                                     seq_lens)
+        new_cache = {"k": kh, "v": vh, "pos": positions,
                      "count": jnp.asarray(s, jnp.int32)}
     elif cache is not None and append:
-        new_cache = _cache_write_many(cache, k, v, positions)
+        new_cache = _cache_write_many(cache, *_grid_rows(ctx, k, v),
+                                      positions)
         kv_valid = new_cache["pos"] >= 0
         o = L.decode_attention_sharded(ctx, q,
                                        _kv_read(new_cache, "k", q.dtype),
@@ -428,7 +474,8 @@ def attn_apply(arch: ArchConfig, p: dict, x: jax.Array, ctx=None, *,
                                        causal=causal, window=window,
                                        prefix_len=prefix_len)
     elif cache is not None and s == 1:
-        new_cache = _cache_write(cache, k, v, positions, layer)
+        new_cache = _cache_write(cache, *_grid_rows(ctx, k, v), positions,
+                                 layer)
         view = new_cache if layer is None else _layer_view(new_cache, layer)
         kv_valid = view["pos"] >= 0
         o = L.decode_attention_sharded(ctx, q,
@@ -438,25 +485,26 @@ def attn_apply(arch: ArchConfig, p: dict, x: jax.Array, ctx=None, *,
                                        causal=causal, window=window,
                                        prefix_len=prefix_len)
     else:
-        o = L.attention_sharded(ctx, q, k, v, positions, positions,
+        kh, vh = k.swapaxes(1, 2), v.swapaxes(1, 2)  # [B, G, S, D]
+        o = L.attention_sharded(ctx, q, kh, vh, positions, positions,
                                 kv_valid_in, causal=causal, window=window,
                                 prefix_len=prefix_len)
         if cache is not None:  # prefill: fill the cache with the suffix
-            t = cache["k"].shape[1]
+            t = cache["pos"].shape[-1]
             if seq_lens is not None and window:
-                new_cache = _ring_exact_fill(cache, k, v, seq_lens, s)
+                new_cache = _ring_exact_fill(cache, kh, vh, seq_lens, s)
             elif s >= t:
                 new_cache = dict(cache)
-                for name, u in _kv_leaves(cache, k, v):
-                    new_cache[name] = u[:, -t:]
+                for name, u in _kv_leaves(cache, kh, vh):
+                    new_cache[name] = u[:, :, -t:]
                 new_cache["pos"] = positions[:, -t:]
                 new_cache["count"] = jnp.asarray(s, jnp.int32)
             else:
                 pad = t - s
                 new_cache = dict(cache)
-                for name, u in _kv_leaves(cache, k, v):
+                for name, u in _kv_leaves(cache, kh, vh):
                     new_cache[name] = jnp.pad(
-                        u, ((0, 0), (0, pad)) + ((0, 0),) * (u.ndim - 2))
+                        u, ((0, 0), (0, 0), (0, pad), (0, 0)))
                 new_cache["pos"] = jnp.pad(positions, ((0, 0), (0, pad)),
                                            constant_values=-1)
                 new_cache["count"] = jnp.asarray(s, jnp.int32)
@@ -506,7 +554,8 @@ def cross_attn_apply(arch: ArchConfig, p: dict, x: jax.Array, enc: jax.Array,
     kp = jnp.zeros((b, t), jnp.int32)
     kv_valid = (jnp.arange(t)[None, :] < enc_lens[:, None]
                 if enc_lens is not None else None)
-    o = L.attention(q, k, v, qp, kp, kv_valid, causal=False)
+    o = L.attention(q, k.swapaxes(1, 2), v.swapaxes(1, 2), qp, kp, kv_valid,
+                    causal=False)
     return x + o.reshape(b, s, arch.q_dim) @ p["xwo"]
 
 

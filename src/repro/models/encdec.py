@@ -60,7 +60,8 @@ def make_caches(arch: ArchConfig, batch: int, length: int, dtype=jnp.bfloat16,
 
 
 def cache_dims(arch: ArchConfig, kv_quant: bool = False) -> Dict:
-    kv = {"k": (None, "batch", "tp", None, None), "v": (None, "batch", "tp", None, None),
+    # head-major [L, B, G, T, D], sharded over the sequence like ``pos``
+    kv = {"k": (None, "batch", None, "tp", None), "v": (None, "batch", None, "tp", None),
           "pos": (None, "batch", "tp"), "count": (None,)}
     if kv_quant:
         kv["k_scale"] = kv["k"][:-1] + (None,)

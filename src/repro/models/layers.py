@@ -86,7 +86,7 @@ def _mask(q_pos, kv_pos, kv_valid, causal: bool, window: int, prefix_len):
 
 
 def _attend_block(q, k, v, mask):
-    """One (q-block × full-kv) online-softmax pass. q:[B,Sq,H,D] k,v:[B,T,G,D].
+    """One (q-block × full-kv) online-softmax pass. q:[B,Sq,H,D] k,v:[B,G,T,D].
 
     Pure-jnp oracle of kernels/flash_attention.py. Everything inside the
     "flashattn" scope stays in VMEM on the TPU kernel path; the HLO
@@ -97,24 +97,30 @@ def _attend_block(q, k, v, mask):
     any GSPMD resharding of the score/probability tensors moves bf16, and
     the softmax normalisation happens in the grouped [B,G,rep,…] layout so
     no reshape crosses the head-sharded dim boundary.
+
+    K/V are head-major, the order the dense K/V grid stores them in and
+    the order both dots contract in: decode reads a layer's slab of the
+    grid where it lies, with no relayout in between.
     """
     b, sq, h, d = q.shape
-    t, g = k.shape[1], k.shape[2]
+    g = k.shape[1]
     rep = h // g
     scale = jnp.asarray(1.0 / math.sqrt(d), q.dtype)
     qg = (q * scale).reshape(b, sq, g, rep, d)
-    scores = jnp.einsum("bsgrd,btgd->bgrst", qg, k,
+    scores = jnp.einsum("bsgrd,bgtd->bgrst", qg, k,
                         preferred_element_type=jnp.float32)
     scores = jnp.where(mask[:, None, None, :, :], scores, NEG_INF)
     m = jnp.max(scores, axis=-1, keepdims=True)
     m = jnp.maximum(m, NEG_INF)  # rows with no valid kv stay finite
     p = jnp.exp(scores - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
-    o = jnp.einsum("bgrst,btgd->bsgrd", p.astype(q.dtype), v,
+    # the output stays grouped, [B,G,rep,Sq,D]: with it query-major the
+    # CPU backend runs this bf16 dot on a routine that has no bf16
+    o = jnp.einsum("bgrst,bgtd->bgrsd", p.astype(q.dtype), v,
                    preferred_element_type=jnp.float32)
     # normalise in grouped layout (no cross-shard reshape), then flatten
-    o = o / jnp.maximum(l[..., 0].transpose(0, 3, 1, 2)[..., None], 1e-30)
-    return (o.astype(q.dtype).reshape(b, sq, h, d),
+    o = o / jnp.maximum(l, 1e-30)
+    return (o.astype(q.dtype).transpose(0, 3, 1, 2, 4).reshape(b, sq, h, d),
             m[..., 0], l[..., 0])  # m,l: [B,G,rep,Sq]
 
 
@@ -124,13 +130,13 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
               causal: bool = True, window: int = 0,
               prefix_len: Optional[jax.Array] = None,
               q_block: int = 1024) -> jax.Array:
-    """GQA attention. q:[B,Sq,H,D]; k,v:[B,T,G,D]; positions int32.
+    """GQA attention. q:[B,Sq,H,D]; k,v:[B,G,T,D]; positions int32.
 
     For Sq > q_block, scans over query blocks (the kv axis is processed in
     one shot per block — the flash kernel tiles it further on TPU).
     """
     b, sq, h, d = q.shape
-    t = k.shape[1]
+    t = k.shape[2]
     if kv_valid is None:
         kv_valid = jnp.ones((b, t), dtype=bool)
 
@@ -180,14 +186,14 @@ def attention_sharded(ctx, q, k, v, q_pos, kv_pos, kv_valid=None, *,
     from jax import shard_map
 
     b, s, h, d = q.shape
-    t, g = k.shape[1], k.shape[2]
+    g, t = k.shape[1], k.shape[2]
     tp = ctx.plan.degree(ctx.plan.tp_axes)
     if tp > 1 and h % tp != 0:
         return attention(q, k, v, q_pos, kv_pos, kv_valid, causal=causal,
                          window=window, prefix_len=prefix_len, q_block=q_block)
     if tp > 1 and g % tp != 0:
-        k = jnp.repeat(k, h // g, axis=2)  # broadcast KV to full heads
-        v = jnp.repeat(v, h // g, axis=2)
+        k = jnp.repeat(k, h // g, axis=1)  # broadcast KV to full heads
+        v = jnp.repeat(v, h // g, axis=1)
         g = h
     if kv_valid is None:
         kv_valid = jnp.ones((b, t), dtype=bool)
@@ -195,7 +201,7 @@ def attention_sharded(ctx, q, k, v, q_pos, kv_pos, kv_valid=None, *,
         prefix_len = jnp.full((b,), -1, jnp.int32)  # <0: no prefix override
 
     qs = ctx.spec(q.shape, ("batch", "seq", "tp", None))
-    ks = ctx.spec(k.shape, ("batch", None, "tp", None))
+    ks = ctx.spec(k.shape, ("batch", "tp", None, None))
     ps = ctx.spec(q_pos.shape, ("batch", "seq"))
     kp = ctx.spec(kv_pos.shape, ("batch", None))
     kvd = ctx.spec(kv_valid.shape, ("batch", None))
@@ -234,7 +240,7 @@ def decode_attention_sharded(ctx, q, k, v, q_pos, kv_pos, kv_valid, *,
     from jax import shard_map
 
     b, s, h, d = q.shape
-    t, g = k.shape[1], k.shape[2]
+    t = k.shape[2]
     tp_axes = ctx.plan.tp_axes
     tp = ctx.plan.degree(tp_axes)
     if tp <= 1 or t % tp != 0 or s != 1:
@@ -244,12 +250,12 @@ def decode_attention_sharded(ctx, q, k, v, q_pos, kv_pos, kv_valid, *,
         prefix_len = jnp.full((b,), -1, jnp.int32)
 
     qs = ctx.spec(q.shape, ("batch", None, None, None))
-    ks = ctx.spec(k.shape, ("batch", "tp", None, None))
+    ks = ctx.spec(k.shape, ("batch", None, "tp", None))
     pqs = ctx.spec(q_pos.shape, ("batch", None))
     pks = ctx.spec(kv_pos.shape, ("batch", "tp"))
     kvs = ctx.spec(kv_valid.shape, ("batch", "tp"))
     pls = ctx.spec(prefix_len.shape, ("batch",))
-    used = ks[1]  # axes actually sharding the cache seq dim
+    used = ks[2]  # axes actually sharding the cache seq dim
     axis_names = tuple(used) if isinstance(used, tuple) else (used,) if used else ()
     if not axis_names:
         return attention(q, k, v, q_pos, kv_pos, kv_valid, causal=causal,

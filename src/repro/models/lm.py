@@ -207,7 +207,8 @@ def make_caches(arch: ArchConfig, batch: int, length: int, dtype=jnp.bfloat16,
 
 
 def cache_dims(arch: ArchConfig, kv_quant: bool = False) -> Dict:
-    """Sharding roles for cache trees (kv: batch + tp over kv heads)."""
+    """Sharding roles for cache trees (head-major K/V ``[B, G, T, D]``:
+    batch, and tp over either the sequence or the kv heads)."""
     prefix, repeats, suffix = stack_structure(arch)
 
     def kv_roles(kind):
@@ -216,12 +217,12 @@ def cache_dims(arch: ArchConfig, kv_quant: bool = False) -> Dict:
             if explicit_spmd_enabled():
                 # cache sharded over its sequence dim (flash-decoding
                 # partials; kv-head counts rarely divide the TP degree)
-                roles = {"k": ("batch", "tp", None, None),
-                         "v": ("batch", "tp", None, None),
-                         "pos": ("batch", "tp"), "count": ()}
-            else:
                 roles = {"k": ("batch", None, "tp", None),
                          "v": ("batch", None, "tp", None),
+                         "pos": ("batch", "tp"), "count": ()}
+            else:
+                roles = {"k": ("batch", "tp", None, None),
+                         "v": ("batch", "tp", None, None),
                          "pos": ("batch", None), "count": ()}
             if kv_quant:
                 # scales ride the same batch/length layout as the payload

@@ -28,8 +28,8 @@ Three layouts, one code path:
   no bookkeeping (serving weights: reduce all but the output-feature
   axis).
 * per-token    — a per-channel special case over the head dimension
-  (KV-cache rows: scale shaped ``[B, T, G, 1]`` rides next to the int8
-  ``[B, T, G, D]`` cache leaf and pages/splices with it structurally).
+  (KV-cache rows: scale shaped ``[B, G, T, 1]`` rides next to the int8
+  ``[B, G, T, D]`` cache leaf and pages/splices with it structurally).
 
 ``QuantConfig`` is the user surface (``ServeConfig(quant=...)``) and the
 planner input (``capacity_bytes`` shrinks weight/KV bytes under it).

@@ -13,8 +13,9 @@ the long tail, and identical prompt prefixes can share physical pages.
 
 Layout
 ------
-Every attention layer's dense cache ``{k [B,T,G,D], v, pos, count}``
-becomes a pool pair ``{"kp": [P, ps, G, D], "vp": [P, ps, G, D]}``
+Every attention layer's head-major dense cache ``{k [B,G,T,D], v, pos,
+count}`` becomes a token-major pool pair ``{"kp": [P, ps, G, D], "vp":
+[P, ps, G, D]}``, so that a page holds whole token rows
 (body-stack layers carry a leading repeats axis, mirroring
 ``models.lm.make_caches``). Page 0 is the reserved **null page**: decode
 writes of inactive slots and masked splice writes land there, so the
@@ -278,8 +279,9 @@ def make_paged_caches(arch, kv_pages: int, page_size: int,
         for row_name, pool_name in _POOL_NAMES:
             if row_name not in kv:
                 continue
-            leaf = kv[row_name]  # [..., 1, ps, G, ·] — swap batch-1 for P
-            shape = leaf.shape[:-4] + (kv_pages,) + leaf.shape[-3:]
+            leaf = kv[row_name]  # [..., 1, G, ps, ·] → [..., P, ps, G, ·]
+            g, ps, d = leaf.shape[-3:]
+            shape = leaf.shape[:-4] + (kv_pages, ps, g, d)
             out[pool_name] = jnp.zeros(shape, leaf.dtype)
         return out
 
@@ -311,8 +313,11 @@ def paged_cache_axes(arch, page_size: int, dtype=jnp.bfloat16,
 
 def _pool_scatter(pool: jax.Array, rows: jax.Array, pages: jax.Array,
                   slots: jax.Array) -> jax.Array:
-    """Scatter ``rows [n, S, ...]`` into ``pool [(R,) P, ps, ...]`` at
-    ``(pages, slots) [n, S]``. A leading repeats axis vmaps."""
+    """Scatter dense head-major ``rows [n, G, S, ·]`` into the token-major
+    ``pool [(R,) P, ps, G, ·]`` at ``(pages, slots) [n, S]``. A leading
+    repeats axis vmaps."""
+    rows = rows.swapaxes(-3, -2)  # [(R,) n, S, G, ·]
+
     def one(p, r):
         return p.at[pages, slots].set(r.astype(p.dtype))
     if pool.ndim == 4:              # flat pool [P, ps, G, D], rows [n, S, G, D]

@@ -203,24 +203,49 @@ def _grid_shaped_ops(hlo: str, shape: str):
     return ops
 
 
+def _unfused_ops(hlo: str):
+    """(opcode, fusion kind, result shapes) of every instruction in a
+    computation that no fusion calls (the entry, loop bodies): the ops
+    that read and write HBM. A fusion's own instructions are left out."""
+    fused = set(re.findall(r" fusion\(.*?calls=%([\w.-]+)", hlo))
+    inst = re.compile(r"^\s*(?:ROOT )?%\S+ = (.+?) ([\w-]+)\(")
+    ops, inside = [], False
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.-]+) .*\{$", line)
+        if head:
+            inside = head.group(1) not in fused
+            continue
+        m = inst.match(line) if inside else None
+        if m:
+            kind = re.search(r"kind=(k\w+)", line)
+            shapes = [tuple(int(x) for x in dims.split(",") if x)
+                      for dims in re.findall(r"\w+\[([\d,]*)\]", m.group(1))]
+            ops.append((m.group(2), kind.group(1) if kind else None, shapes))
+    return ops
+
+
+def _yi9b_l16():
+    return dataclasses.replace(repro.get_arch("yi-9b"), num_layers=16)
+
+
 def test_yi9b_serve_step_writes_kv_grid_in_place(topo):
     """Yi-9B at published widths (d_model 4096, 32/4 heads of 128, d_ff
     11008, vocabulary 64000), 16 layers, 32 slots × 4096: the benchmark's
-    ``yi-9b-l16`` serve step. Decode carries the stacked K/V grid
-    ``bf16[16,32,4096,4,128]`` (2 × 2.0 GiB) through the layer scan and
-    scatters one token row per layer into it.
+    ``yi-9b-l16`` serve step. Decode carries the stacked head-major K/V
+    grid ``bf16[16,32,4,4096,128]`` (2 × 2.0 GiB) through the layer scan
+    and scatters one token row per layer into it.
 
     Before (grid scanned as per-layer inputs and outputs): 4.51 GiB of
     temporaries, and grid-sized ``copy`` ×2, ``AllocateBuffer`` ×2 and
-    ``dynamic-update-slice`` loop fusions ×2. After: 0.126 GiB, and the
-    only grid-sized results are the two in-place scatter fusions. The
-    bound, one layer's K and V slabs (0.25 GiB), also refuses carrying the
-    grid but writing each layer's whole slab back (0.38 GiB)."""
-    arch = dataclasses.replace(repro.get_arch("yi-9b"), num_layers=16)
+    ``dynamic-update-slice`` loop fusions ×2. After: the only grid-sized
+    results are the two in-place scatter fusions. The bound, one layer's
+    K and V slabs (0.25 GiB), also refuses carrying the grid but writing
+    each layer's whole slab back (0.38 GiB)."""
+    arch = _yi9b_l16()
     slots, max_len = 32, 4096
     compiled = _serve_step_compiled(topo, arch, slots=slots, max_len=max_len)
     mem = compiled.memory_analysis()
-    shape = (arch.num_layers, slots, max_len, arch.num_kv_heads, arch.head_dim)
+    shape = (arch.num_layers, slots, arch.num_kv_heads, max_len, arch.head_dim)
     grid_bytes = 2 * int(np.prod(shape)) * 2  # K and V, bf16
     assert mem.alias_size_in_bytes >= grid_bytes  # the donated grid
     assert mem.temp_size_in_bytes < grid_bytes / arch.num_layers, (
@@ -231,14 +256,50 @@ def test_yi9b_serve_step_writes_kv_grid_in_place(topo):
                        for op, kind in ops), ops
 
 
+@pytest.mark.parametrize("config", ["yi-9b-l16", "yi-9b-2x2"])
+def test_yi9b_serve_step_reads_kv_slab_in_place(topo, config):
+    """Decode attention reads layer ``i``'s K and V slab where it lies in
+    the carried head-major grid: the layer's ``dynamic-slice`` fuses into
+    the two attention dot fusions, as the weights' slices do.
+
+    With the grid stored token-major ``[L, B, t, G, D]`` the chip's
+    compiler copied each layer's slab out and relaid it to the
+    ``(b, g, t, d)`` order of the dots: a ``constant_dynamic-slice``
+    fusion and a ``copy`` per slab, 128 MiB of temporaries on one chip
+    (``yi-9b-l16``), 64 MiB per chip on the 2x2 host (``yi-9b-2x2``).
+    Storing it head-major, with the token written head by head, leaves
+    no such op and (almost) no temporaries. The bound is a sixteenth of
+    one layer's K slab on one chip; no unfused ``copy``, ``transpose``,
+    ``dynamic-slice`` or fusion may have a slab's element count, in any
+    order."""
+    if config == "yi-9b-l16":
+        arch, slots, max_len, grid = _yi9b_l16(), 32, 4096, (1, 1)
+    else:
+        arch, slots, max_len, grid = repro.get_arch("yi-9b"), 128, 2048, (2, 2)
+    chips = grid[0] * grid[1]
+    compiled = _serve_step_compiled(topo, arch, slots=slots, max_len=max_len,
+                                    grid=grid)
+    slab = slots * arch.num_kv_heads * (max_len // chips) * arch.head_dim
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < slab * 2 / 16, (
+        f"temp {mem.temp_size_in_bytes / (1 << 20):.2f} MiB")
+    slab_ops = [(op, kind, shapes)
+                for op, kind, shapes in _unfused_ops(compiled.as_text())
+                if op in ("copy", "transpose", "dynamic-slice", "fusion")
+                and any(int(np.prod(sh)) == slab for sh in shapes)]
+    assert not slab_ops, slab_ops
+
+
 def test_yi9b_whole_serve_step_fits_a_2x2_host(topo):
     """Yi-9B whole (48 layers at published widths), 128 slots × 2048: the
     benchmark's ``yi-9b-2x2`` serve step on a described v5e 2x2, at the
     plan's shardings (4-way tensor parallel over the data × model grid
     ``repro.plan`` fits to four devices, the K/V grid split by sequence).
 
-    Today: 10.87 GB of arguments per device (the donated grid 6.46 GB of
-    them) and 0.068 GB of temporaries. Per layer, 5 all-reduces (the two
+    10.87 GB of arguments per device (the donated grid 6.46 GB of them).
+    Temporaries: 0.068 GB while the grid was stored token-major (each
+    layer's K/V slab relaid for the attention dots), none since it is
+    head-major. Per layer, 5 all-reduces (the two
     row-parallel outputs ``bf16[128,1,4096]``; flash-decoding's merge,
     ``f32[128,1,32,128]`` and two ``f32[128,1,32,1]``) and 6 all-gathers
     (the new K/V rows ``bf16[128,4,128]`` ×4, the queries
@@ -256,9 +317,9 @@ def test_yi9b_whole_serve_step_fits_a_2x2_host(topo):
     mem = compiled.memory_analysis()
     per_device = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert per_device < 15.75e9, f"{per_device / 1e9:.2f} GB"
-    # one layer's K and V slabs on one device
-    slab = 2 * slots * max_len * arch.num_kv_heads * arch.head_dim * 2 // chips
-    assert mem.temp_size_in_bytes < slab, (
+    # a sixteenth of one layer's K slab on one device
+    slab = slots * max_len * arch.num_kv_heads * arch.head_dim * 2 // chips
+    assert mem.temp_size_in_bytes < slab / 16, (
         f"temp {mem.temp_size_in_bytes / 1e9:.3f} GB")
     stats = collective_stats(compiled)
     assert max(v["max_bytes"] for v in stats.values()) <= 16e6, stats

@@ -2,10 +2,11 @@
 
 ``LM.forward`` carries the body's stacked caches through the layer scan
 when it decodes one token on the dense grid: each layer scatters its new
-K/V row at ``[layer, row, pos % t]`` and attends over its layer of the
-carried grid. These tests hold that path bit for bit to the per-layer
-semantics it replaced: every block reads and writes its own
-``[B, t, ...]`` slice of the grid through ``blocks.attn_apply`` (or the
+K/V row at ``[layer, row, :, pos % t]`` of the head-major grid and
+attends over its layer of the carried grid. These tests hold that path
+bit for bit to the per-layer semantics it replaced: every block reads and
+writes its own ``[B, G, t, ...]`` slice of the grid through
+``blocks.attn_apply`` (or the
 recurrent block's apply), as the scan over per-layer inputs and outputs
 still does for prefill, append and paged forwards.
 """
@@ -140,14 +141,19 @@ def test_decode_step_in_place_matches_per_layer(name, key):
     np.testing.assert_array_equal(np.asarray(record["emit"]), ACTIVE)
 
 
-@pytest.mark.parametrize("kv_quant", [False, True], ids=["fp", "int8"])
-def test_token_write_matches_row_update(kv_quant, key):
+@pytest.mark.parametrize(
+    "kv_quant,stacked", [(False, True), (True, True), (False, False),
+                         (True, False)],
+    ids=["fp", "int8", "fp-per-layer", "int8-per-layer"])
+def test_token_write_matches_row_update(kv_quant, stacked, key):
     """The scatter writes what a per-row ``dynamic_update_slice`` at
-    ``pos % t`` writes, into a per-layer slice and into layer ``i`` of a
-    stacked grid alike."""
+    ``[row, :, pos % t]`` of the head-major ``[B, G, t, ·]`` leaves
+    writes, into layer ``i`` of a stacked grid and into a per-layer
+    slice alike."""
     arch = repro.get_arch("qwen1.5-0.5b").reduced()
     t, g, d = 8, arch.num_kv_heads, arch.head_dim
     cache = B.make_kv_cache(arch, SLOTS, t, jnp.float32, kv_quant=kv_quant)
+    assert cache["k"].shape == (SLOTS, g, t, d)
     kk, kv = jax.random.split(key)
     k_new = jax.random.normal(kk, (SLOTS, 1, g, d))
     v_new = jax.random.normal(kv, (SLOTS, 1, g, d))
@@ -155,18 +161,73 @@ def test_token_write_matches_row_update(kv_quant, key):
     slot = pos_new[:, 0] % t
 
     def row_update(c, u):
+        """c [B, G, t, ·] (or pos [B, t]), u the fresh [B, 1, G, ·] rows
+        (or [B, 1]), written head-major at ``[:, slot]`` of each row."""
+        if c.ndim == 2:
+            return jax.vmap(lambda c_, u_, i: jax.lax.dynamic_update_slice(
+                c_, u_.astype(c_.dtype), (i,)))(c, u, slot)
         return jax.vmap(lambda c_, u_, i: jax.lax.dynamic_update_slice(
-            c_, u_.astype(c_.dtype), (i,) + (0,) * (c_.ndim - 1)))(c, u, slot)
+            c_, u_.swapaxes(0, 1).astype(c_.dtype), (0, i, 0)))(c, u, slot)
 
     want = dict(cache)
     for name, u in B._kv_leaves(cache, k_new, v_new):
         want[name] = row_update(cache[name], u)
     want["pos"] = row_update(cache["pos"], pos_new)
     want["count"] = cache["count"] + 1
-    _assert_trees_equal(B._cache_write(cache, k_new, v_new, pos_new), want)
-
+    if not stacked:
+        _assert_trees_equal(B._cache_write(cache, k_new, v_new, pos_new),
+                            want)
+        return
     grid = jax.tree.map(lambda a: jnp.stack([a] * 3), cache)
     got = B._cache_write(grid, k_new, v_new, pos_new, jnp.int32(1))
     _assert_trees_equal(jax.tree.map(lambda a: a[1], got), want)
     _assert_trees_equal(jax.tree.map(lambda a: a[::2], got),
                         jax.tree.map(lambda a: a[::2], grid))
+
+
+@pytest.mark.parametrize("name", ["dense", "dense-int8kv", "moe-prefix"])
+def test_paged_round_trip_matches_dense_grid(name, key):
+    """Prefill rows spliced into the dense grid (``splice_rows``) and into
+    a paged pool (``splice_pages``, which turns the head-major rows
+    token-major) decode the same tokens, with bit-equal hidden states:
+    the pool holds what the grid holds, whatever order each stores."""
+    from repro.serving import pages as PG
+    from repro.serving.scheduler import invalidate_padding, splice_rows
+
+    arch, kv_quant = ARCHS[name]
+    ps = 8
+    m = MAX_LEN // ps
+    kp, kt = jax.random.split(key)
+    params = REG.init_params(arch, kp, jnp.float32)
+    prompt = jax.random.randint(kt, (SLOTS, BUCKET), 0, arch.vocab_size)
+    axes = REG.cache_axes(arch, jnp.float32, kv_quant=kv_quant)
+    lens = jnp.asarray(LENS)
+
+    def prefill(p, toks):
+        rows = REG.make_caches(arch, SLOTS, BUCKET, jnp.float32,
+                               kv_quant=kv_quant)
+        h, rows = LM.forward(arch, p, toks, caches=rows, seq_lens=lens)
+        last = jnp.take_along_axis(h, (lens - 1)[:, None, None], axis=1)
+        return invalidate_padding(rows, lens, axes), last
+
+    rows, h_last = jax.jit(prefill)(params, prompt)
+    dense = splice_rows(REG.make_caches(arch, SLOTS, MAX_LEN, jnp.float32,
+                                        kv_quant=kv_quant),
+                        rows, jnp.arange(SLOTS, dtype=jnp.int32), axes)
+    table = 1 + jnp.arange(SLOTS * m, dtype=jnp.int32).reshape(SLOTS, m)
+    pools = PG.splice_pages(
+        PG.make_paged_caches(arch, 1 + SLOTS * m, ps, jnp.float32,
+                             kv_quant=kv_quant), rows, table)
+
+    step_dense = jax.jit(lambda p, c, t, pos: LM.forward(
+        arch, p, t, caches=c, positions=pos))
+    step_paged = jax.jit(lambda p, c, t, pos: LM.forward(
+        arch, p, t, caches=c, positions=pos, page_table=table))
+    tokens = jnp.argmax(LM.logits_fn(arch, params, h_last), axis=-1)
+    positions = lens[:, None]
+    for _ in range(3):
+        h_d, dense = step_dense(params, dense, tokens, positions)
+        h_p, pools = step_paged(params, pools, tokens, positions)
+        np.testing.assert_array_equal(np.asarray(h_p), np.asarray(h_d))
+        tokens = jnp.argmax(LM.logits_fn(arch, params, h_d), axis=-1)
+        positions = positions + 1

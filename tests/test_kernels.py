@@ -184,7 +184,8 @@ def test_model_attention_matches_kernel(key):
     k = jax.random.normal(k2, (b, s, h, d))
     v = jax.random.normal(k3, (b, s, h, d))
     pos = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-    out_model = L.attention(q, k, v, pos, pos, causal=True, q_block=64)
+    out_model = L.attention(q, k.swapaxes(1, 2), v.swapaxes(1, 2), pos, pos,
+                            causal=True, q_block=64)  # K/V head-major
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, s, d)
     kf = k.transpose(0, 2, 1, 3).reshape(b * h, s, d)
     vf = v.transpose(0, 2, 1, 3).reshape(b * h, s, d)

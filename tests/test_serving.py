@@ -31,12 +31,13 @@ def test_cache_axes_metadata_matches_constructors():
     every family — including leaves whose batch axis is not leading."""
     ax = REG.cache_axes(ARCH)
     body = ax["body"]["b0_attn"]
-    assert (body["k"].batch, body["k"].length) == (1, 2)
+    # K/V are head-major, [L, B, G, T, D]; pos is [L, B, T]
+    assert (body["k"].batch, body["k"].length) == (1, 3)
     assert (body["pos"].batch, body["pos"].length) == (1, 2)
     assert (body["count"].batch, body["count"].length) == (None, None)
 
     moe = REG.cache_axes(repro.get_arch("deepseek-moe-16b").reduced())
-    assert (moe["prefix0"]["k"].batch, moe["prefix0"]["k"].length) == (0, 1)
+    assert (moe["prefix0"]["k"].batch, moe["prefix0"]["k"].length) == (0, 2)
 
     rec = REG.cache_axes(repro.get_arch("recurrentgemma-2b").reduced())
     flat = jax.tree_util.tree_flatten_with_path(
@@ -50,7 +51,7 @@ def test_cache_axes_metadata_matches_constructors():
 
     enc = REG.cache_axes(repro.get_arch("seamless-m4t-medium").reduced())
     k = enc["dec_body"]["k"]
-    assert (k.batch, k.length) == (1, 2)  # layer-stacked: batch axis is NOT 0
+    assert (k.batch, k.length) == (1, 3)  # layer-stacked: batch axis is NOT 0
 
 
 def test_splice_row_regression_slots_collide_with_model_dim():
