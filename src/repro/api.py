@@ -223,9 +223,7 @@ class Executable:
         admission KV across (``ExecutionPlan.disaggregate``).
 
         ``on_step`` is the engine's step-timing hook: called after every
-        decode step with ``{"step", "wall_s", "tokens"}`` — the probe
-        ``repro.bench`` uses to put measured step time next to the plan's
-        ``predicted_seconds`` (the paper's model-validation loop).
+        decode step with ``{"step", "wall_s", "tokens"}``.
 
         The pre-``ServeConfig`` flat kwargs (``slots=, max_len=, paged=,
         ...``) are still accepted — funneled through

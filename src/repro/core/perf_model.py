@@ -4,11 +4,11 @@ Two domains are provided:
 
 * **cycle domain** (`TilePipelineModel.cycles`): the paper's formulation
   verbatim — AXI-stream port counts ⟨Ip, Wp, Op⟩, latencies in clock
-  cycles. Used by the paper-parity benchmarks (Tables 1/3/4, Figs 3/14/15).
+  cycles. Used by the paper reproduction, ``repro.paper`` (Tables 1/3/4,
+  Figs 3/14/15).
 * **time domain** (`TilePipelineModel.seconds`): the TPU v5e adaptation —
   ports become fractions of HBM bandwidth, the MAC array becomes the MXU,
-  inter-FPGA links become ICI rings. Used by the planner and the roofline
-  report.
+  inter-FPGA links become ICI rings. Used by the planner.
 
 The model's defining property (the paper's Challenge 1): the pipeline is a
 **max over concurrent streams**, not a sum of aggregate costs — a design
@@ -23,43 +23,6 @@ from typing import Optional
 from repro.core import hw
 from repro.core.layer_model import ConvLayer
 from repro.core.partition import PartitionFactors
-
-
-@dataclasses.dataclass(frozen=True)
-class Calibration:
-    """Calibratable constants for the time-domain model.
-
-    The paper validates its analytic model against measured runs (<3%
-    error); the TPU/CPU adaptation does the same by scaling the three
-    roofs to the *effective* rates the host actually achieves.  A scale of
-    1.0 means "the hardware hits its datasheet roof"; real machines sit
-    below that, and ``repro.bench.calibrate`` fits these from measured
-    runs (time = uncalibrated_time / scale).
-
-    ``overhead_s`` is a fixed per-layer dispatch/launch cost added to the
-    assembled total — the term that dominates tiny layers.
-    """
-
-    flops_scale: float = 1.0  # effective fraction of peak MXU/ALU rate
-    hbm_scale: float = 1.0    # effective fraction of peak memory bandwidth
-    ici_scale: float = 1.0    # effective fraction of peak interconnect bw
-    overhead_s: float = 0.0   # per-layer fixed dispatch overhead (seconds)
-
-    def as_dict(self) -> dict:
-        return dataclasses.asdict(self)
-
-    @classmethod
-    def from_dict(cls, d: dict) -> "Calibration":
-        known = {f.name for f in dataclasses.fields(cls)}
-        return cls(**{k: float(v) for k, v in d.items() if k in known})
-
-    @property
-    def identity(self) -> bool:
-        return (self.flops_scale == 1.0 and self.hbm_scale == 1.0
-                and self.ici_scale == 1.0 and self.overhead_s == 0.0)
-
-
-IDENTITY_CALIBRATION = Calibration()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -167,8 +130,6 @@ class TilePipelineModel:
     """Evaluate Eqs. 8–14 (+ XFER Eqs. 16–21) for a layer/partition/tiling."""
 
     hw_spec: hw.HardwareSpec = dataclasses.field(default_factory=lambda: hw.V5E)
-    # Fitted by repro.bench.calibrate; identity = datasheet roofs.
-    calib: Calibration = dataclasses.field(default_factory=Calibration)
 
     # ---------------- cycle domain (paper-faithful) ----------------
     def cycles(self, layer: ConvLayer, t: Tiling, ports: Ports,
@@ -218,7 +179,6 @@ class TilePipelineModel:
         bpe = layer.bytes_per_elem
         K = layer.K
         s = self.hw_spec
-        c = self.calib
         B, R, C, M, N = _device_dims(layer, p)
 
         flops_tile = 2.0 * K * K * t.Tr * t.Tc * t.Tm * t.Tn
@@ -226,13 +186,13 @@ class TilePipelineModel:
         # size waste lanes (paper Eqs. 1–2 analogue).
         eff = min(t.Tm / s.mxu_dim, 1.0) * min(t.Tn / s.mxu_dim, 1.0)
         eff = max(eff, 1e-3) if (t.Tm < s.mxu_dim or t.Tn < s.mxu_dim) else 1.0
-        t_comp = flops_tile / (s.matmul_flops_per_s(dtype) * eff * c.flops_scale)
+        t_comp = flops_tile / (s.matmul_flops_per_s(dtype) * eff)
 
-        bw = s.hbm_bandwidth * c.hbm_scale
+        bw = s.hbm_bandwidth
         t_ifm = t.Tn * t.Tr * t.Tc * bpe / (ports.Ip * bw)
         t_ofm = t.Tm * t.Tr * t.Tc * bpe / (ports.Op * bw)
         wsd, isd = p.weight_shared_degree, p.ifm_shared_degree
-        ici = s.ici_axis_bandwidth() * c.ici_scale
+        ici = s.ici_axis_bandwidth()
         if layer.weighted and xfer and wsd > 1:
             wtile = t.Tm * t.Tn * K * K * bpe
             t_wei = wtile / (ports.Wp * bw * wsd)                       # Eq. 16
@@ -252,22 +212,17 @@ class TilePipelineModel:
             t_reduce = 2.0 * otile * (p.Pn - 1) / p.Pn / ici
 
         return self._assemble(layer, t, B, R, C, M, N, t_comp, t_ifm, t_wei,
-                              t_ofm, t_link_w, t_link_i, t_reduce,
-                              overhead=c.overhead_s)
-
-    def calibrated(self, calib: Calibration) -> "TilePipelineModel":
-        """A copy of this model with fitted constants applied."""
-        return dataclasses.replace(self, calib=calib)
+                              t_ofm, t_link_w, t_link_i, t_reduce)
 
     # ---------------- shared pipeline algebra (Eqs. 12–14) ----------------
     @staticmethod
     def _assemble(layer, t, B, R, C, M, N, t_comp, t_ifm, t_wei, t_ofm,
-                  t_link_w, t_link_i, t_reduce, overhead: float = 0.0) -> LayerLatency:
+                  t_link_w, t_link_i, t_reduce) -> LayerLatency:
         trip_inner = _ceil_div(N, t.Tn)                      # loop C
         trip_outer = B * _ceil_div(R, t.Tr) * _ceil_div(C, t.Tc) * _ceil_div(M, t.Tm)
         lat1 = max(t_comp, t_ifm, t_wei, t_link_w, t_link_i)  # Eq. 12/18/21
         lat2 = max(trip_inner * lat1 + t_reduce, t_ofm)       # Eq. 13
-        total = trip_outer * lat2 + (t_ofm + lat1) + overhead  # Eq. 14 (+dispatch)
+        total = trip_outer * lat2 + (t_ofm + lat1)             # Eq. 14
         return LayerLatency(
             t_comp=t_comp, t_ifm=t_ifm, t_wei=t_wei, t_ofm=t_ofm,
             t_link_w=t_link_w, t_link_i=t_link_i, t_reduce=t_reduce,

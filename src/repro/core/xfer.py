@@ -30,14 +30,6 @@ from repro.core.planner import ShardingPlan
 PyTree = Any
 
 
-def explicit_spmd_enabled() -> bool:
-    """Gate for the explicit shard_map paths (attention locality, EP
-    all-to-all, flash-decoding). Default on; set REPRO_EXPLICIT_SPMD=0 to
-    measure the pure-GSPMD baseline (§Perf before/after)."""
-    import os
-    return os.environ.get("REPRO_EXPLICIT_SPMD", "1") != "0"
-
-
 def _fits(size: int, axes: Sequence[str], axis_sizes: Dict[str, int]) -> Tuple[str, ...]:
     """Longest prefix of `axes` whose product divides `size`."""
     out = []

@@ -1,7 +1,7 @@
 """Persistent XLA compilation cache, placed from outside the program.
 
 Every entry point (``chip_smoke.py``, ``launch/serve.py``,
-``launch/train.py``, ``python -m repro.bench``) calls
+``launch/train.py``) calls
 :func:`enable_compile_cache` once, before its first compile. It is never
 called at package import, so library users and the test suite keep no
 cache unless they ask for one.

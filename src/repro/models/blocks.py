@@ -226,12 +226,11 @@ def _cache_write(cache: dict, k_new, v_new, pos_new, layer=None):
 
 def _grid_rows(ctx, k, v):
     """Fresh K/V rows ``[B, S, G, D]`` in the sharding of the grid they
-    are written to (``lm.cache_dims``): whole on every chip when the grid
-    is split by sequence, as the explicit-SPMD plans split it. Left in
-    the projection's split by head, the rows make the partitioner gather
-    the scatter's indices instead: two more exchanges per layer."""
-    from repro.core.xfer import explicit_spmd_enabled
-    if ctx is None or not explicit_spmd_enabled():
+    are written to (``lm.cache_dims``): whole on every chip, since the
+    grid is split by sequence. Left in the projection's split by head,
+    the rows make the partitioner gather the scatter's indices instead:
+    two more exchanges per layer."""
+    if ctx is None:
         return k, v
     return (ctx.constrain(k, "batch", None, None, None),
             ctx.constrain(v, "batch", None, None, None))
@@ -570,9 +569,7 @@ def moe_apply(arch: ArchConfig, p: dict, h: jax.Array, ctx=None,
     degenerates into full-buffer all-gathers — observed 185 s of collective
     time on deepseek train_4k; the explicit EP path moves only the routed
     tokens, twice, over the model axis)."""
-    from repro.core.xfer import explicit_spmd_enabled
-    if (ctx is not None and ctx.mesh is not None and h.shape[1] > 1
-            and explicit_spmd_enabled()):
+    if ctx is not None and ctx.mesh is not None and h.shape[1] > 1:
         ep_axes = ctx.plan.ep_axes or ctx.plan.tp_axes
         ep = ctx.plan.degree(ep_axes)
         if (len(ep_axes) == 1 and ep > 1 and arch.num_experts % ep == 0):

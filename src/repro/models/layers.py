@@ -178,9 +178,7 @@ def attention_sharded(ctx, q, k, v, q_pos, kv_pos, kv_valid=None, *,
     Falls back to the GSPMD path for decode (s==1) and for head counts not
     divisible by the TP degree (e.g. phi3's 40 heads on 16-way TP).
     """
-    from repro.core.xfer import explicit_spmd_enabled
-    if (ctx is None or ctx.mesh is None or q.shape[1] == 1
-            or not explicit_spmd_enabled()):
+    if ctx is None or ctx.mesh is None or q.shape[1] == 1:
         return attention(q, k, v, q_pos, kv_pos, kv_valid, causal=causal,
                          window=window, prefix_len=prefix_len, q_block=q_block)
     from jax import shard_map
@@ -233,8 +231,7 @@ def decode_attention_sharded(ctx, q, k, v, q_pos, kv_pos, kv_valid, *,
     log-sum-exp weighted psum over the TP axis — two tiny collectives of
     [B,H,D] instead of the cache.
     """
-    from repro.core.xfer import explicit_spmd_enabled
-    if ctx is None or ctx.mesh is None or not explicit_spmd_enabled():
+    if ctx is None or ctx.mesh is None:
         return attention(q, k, v, q_pos, kv_pos, kv_valid, causal=causal,
                          window=window, prefix_len=prefix_len)
     from jax import shard_map

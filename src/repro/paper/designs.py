@@ -1,10 +1,10 @@
-"""Cycle-domain design search for the paper-parity scenarios.
+"""Cycle-domain design search for the paper's tables.
 
 The paper's testbed is a ZCU102 (XCZU9EG): 2520 DSP48, 1824 BRAM18K.
 Cycle-domain searches replicate the paper's design constraints (Eqs. 1-7)
 and port presets (§5A: ⟨2,2,2⟩ @100MHz for 32b float, ⟨4,8,4⟩ @200MHz for
-16b fixed). Formerly ``benchmarks/common.py``; the CNN descriptor sets
-(VGG16/YOLOv1/SqueezeNet) for Fig. 15 live here too.
+16b fixed). The CNN descriptor sets (VGG16/YOLOv1/SqueezeNet) for
+Fig. 15 live here too.
 """
 from __future__ import annotations
 

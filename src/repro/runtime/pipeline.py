@@ -10,7 +10,7 @@ stages. Under SPMD every device executes the same tick loop; a device is
 construction trains.
 
 This exists as a *comparison baseline*: the paper's point (and ours —
-benchmarks/tpu_xfer.py::pipeline_baseline) is that Super-LIP partitioning
+``repro.paper.tpu_study.pipeline_baseline``) is that Super-LIP partitioning
 beats pipelining on latency at equal throughput for low-batch inference.
 """
 from __future__ import annotations

@@ -307,10 +307,10 @@ class ServingEngine:
         # when ServeConfig.elastic is set (see maybe_resize())
         self.migrations: List[MigrationReport] = []
         self.elastic = None
-        # step-timing hooks (repro.bench serve scenarios read these):
-        # wall seconds per step() call and tokens retired per call, plus
-        # host admission-path wall per prefill. Bounded deques: telemetry
-        # covers a sliding window so long-lived engines stay bounded.
+        # step-timing hooks: wall seconds per step() call and tokens
+        # retired per call, plus host admission-path wall per prefill.
+        # Bounded deques: telemetry covers a sliding window so long-lived
+        # engines stay bounded.
         self.on_step = on_step
         self.step_times = deque(maxlen=4096)
         self.step_token_counts = deque(maxlen=4096)

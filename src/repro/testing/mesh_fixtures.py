@@ -8,10 +8,9 @@ with those two constraints:
 
 * :func:`force_host_device_count` edits ``XLA_FLAGS`` by **appending**
   (user-set flags survive; a previous force flag is replaced) and refuses
-  to touch the environment once the backend is initialised — the bug the
-  old ``launch/dryrun.py`` / ``bench/hillclimb.py`` import-time
-  ``os.environ["XLA_FLAGS"] = ...`` overwrite had. It also pins the CPU
-  platform (:func:`pin_cpu_platform`): simulated devices live on the host,
+  to touch the environment once the backend is initialised — the bug an
+  import-time ``os.environ["XLA_FLAGS"] = ...`` overwrite has. It also
+  pins the CPU platform (:func:`pin_cpu_platform`): simulated devices live on the host,
   and a process that initialised the accelerator instead would hold the
   chip another process needs (one process per chip).
 * :func:`fake_devices` is the context-managed form for launcher

@@ -190,3 +190,44 @@ def test_program_names_match_the_device_trace_readers():
     assert re.search(pats.SERVE_STEP, name(serve))
     assert re.search(pats.PREFILL, name(prefill))
     assert not re.search(pats.PREFILL, name(serve))
+
+
+def test_engine_step_timing_hooks(key):
+    arch = repro.get_arch("qwen1.5-0.5b").reduced()
+    seen = []
+    plan = repro.plan(arch, ShapeConfig("hooks", 32, 2, "decode"))
+    engine = plan.compile().serve(config=ServeConfig(slots=2, max_len=32),
+                                  on_step=seen.append)
+    engine.submit(Request(rid=0, prompt=np.arange(1, 5, dtype=np.int32),
+                          max_new_tokens=3))
+    engine.run_until_drained(max_steps=10)
+    stats = engine.step_stats()
+    assert stats["steps"] == len(engine.step_times) > 0
+    assert stats["tokens"] == 3.0
+    assert stats["tokens_per_s"] > 0
+    assert stats["step_p95_ms"] >= stats["step_p50_ms"] > 0
+    assert [s["step"] for s in seen] == list(range(len(engine.step_times)))
+    assert all(s["wall_s"] > 0 for s in seen)
+    # lookahead dispatch: every emitted token is accounted exactly once
+    assert sum(s["tokens"] for s in seen) == 3
+    engine.reset_step_stats()
+    assert len(engine.step_times) == 0 and engine.step_stats()["steps"] == 0
+
+
+def test_engine_prefill_timing_hooks(key):
+    """The admission path records per-request wall time (bucketed prefill
+    dispatch + splice)."""
+    arch = repro.get_arch("qwen1.5-0.5b").reduced()
+    plan = repro.plan(arch, ShapeConfig("hooks_p", 32, 2, "decode"))
+    engine = plan.compile().serve(config=ServeConfig(slots=2, max_len=32))
+    for i, n in enumerate((4, 6, 5)):
+        engine.submit(Request(rid=i, prompt=np.arange(1, n + 1, dtype=np.int32),
+                              max_new_tokens=1))
+    engine.run_until_drained(max_steps=20)
+    stats = engine.prefill_stats()
+    assert stats["prefills"] == 3.0
+    assert stats["prompt_tokens"] == 15.0
+    assert stats["prefill_p95_ms"] >= stats["prefill_p50_ms"] > 0
+    assert stats["prefill_tokens_per_s"] > 0
+    engine.reset_step_stats()
+    assert engine.prefill_stats()["prefills"] == 0.0
