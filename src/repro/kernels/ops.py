@@ -13,6 +13,7 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.mlstm_kernel import mlstm_chunkwise
 from repro.kernels.paged_attention import paged_attention
 from repro.kernels.quant_matmul import quant_matmul
+from repro.kernels.ragged_decode_attention import ragged_decode_attention
 from repro.kernels.rglru_scan import rglru_scan
 from repro.kernels.xfer_matmul import xfer_matmul
 
@@ -47,6 +48,11 @@ def paged_attn(q, kp, vp, page_table, lengths, *, k_scale=None, v_scale=None):
                            interpret=not _on_tpu())
 
 
+def ragged_decode_attn(q, k, v, pos, layer, q_pos, *, window=0):
+    return ragged_decode_attention(q, k, v, pos, layer, q_pos, window=window,
+                                   interpret=not _on_tpu())
+
+
 def mlstm(q, k, v, it, ft, *, bq=256):
     return mlstm_chunkwise(q, k, v, it, ft, bq=bq, interpret=not _on_tpu())
 
@@ -58,3 +64,4 @@ attention_ref = ref.flash_attention_ref
 lru_scan_ref = ref.rglru_scan_ref
 mlstm_ref = ref.mlstm_ref
 paged_attn_ref = ref.paged_attention_ref
+ragged_decode_attn_ref = ref.ragged_decode_attention_ref

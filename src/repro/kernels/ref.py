@@ -72,6 +72,33 @@ def paged_attention_ref(q: jax.Array, kp: jax.Array, vp: jax.Array,
     return o.reshape(b, h, d).astype(q.dtype)
 
 
+def ragged_decode_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
+                                pos: jax.Array, layer, q_pos: jax.Array, *,
+                                window: int = 0) -> jax.Array:
+    """Whole-slab oracle for kernels/ragged_decode_attention.py.
+
+    q: [B, H, D]; k, v: [L, B, G, T, D]; pos: [L, B, T] (−1: empty);
+    layer: the layer attended; q_pos: [B]. Every position of the layer's
+    slab is read and masked as ``models.layers._mask`` masks it; a row
+    with no valid key returns zeros. Returns [B, H, D]."""
+    b, h, d = q.shape
+    g = k.shape[2]
+    rep = h // g
+    kl = k[layer].astype(jnp.float32)  # [B, G, T, D]
+    vl = v[layer].astype(jnp.float32)
+    kp = pos[layer]  # [B, T]
+    qp = q_pos[:, None]
+    valid = (kp >= 0) & (kp <= qp)
+    if window:
+        valid &= (qp - kp) < window
+    qg = q.astype(jnp.float32).reshape(b, g, rep, d) / math.sqrt(d)
+    s = jnp.einsum("bgrd,bgtd->bgrt", qg, kl)
+    s = jnp.where(valid[:, None, None, :], s, NEG_INF)
+    p = jnp.where(valid[:, None, None, :], jax.nn.softmax(s, axis=-1), 0.0)
+    o = jnp.einsum("bgrt,bgtd->bgrd", p, vl)
+    return o.reshape(b, h, d).astype(q.dtype)
+
+
 def rglru_scan_ref(a: jax.Array, b: jax.Array, h0: jax.Array) -> jax.Array:
     """h_t = a_t * h_{t-1} + b_t; returns the h sequence [B, S, W]."""
     def step(h, ab):

@@ -236,6 +236,23 @@ def _grid_rows(ctx, k, v):
             ctx.constrain(v, "batch", None, None, None))
 
 
+def ragged_decode_applies(ctx, grid: dict) -> bool:
+    """Whether single-token decode over the stacked dense ``grid`` runs
+    the ragged Pallas kernel (``kernels/ragged_decode_attention.py``),
+    which reads only each row's live K/V blocks: the program is built
+    for a TPU, on one device (sequence- or batch-sharded grids keep the
+    flash-decoding path), and the grid is unquantised with a length the
+    kernel can block and a head size its block copies can tile (a
+    multiple of 128). Causal attention with no prefix-LM override is the
+    caller's part of the test."""
+    from repro.kernels import ops
+    from repro.kernels.ragged_decode_attention import block_size
+    one_device = ctx is None or ctx.mesh is None or ctx.mesh.size == 1
+    return (ops._on_tpu() and one_device and "k_scale" not in grid
+            and block_size(grid["pos"].shape[-1]) is not None
+            and grid["k"].shape[-1] % 128 == 0)
+
+
 def _layer_view(cache: dict, layer) -> dict:
     """Layer ``layer`` of a stacked grid: the ``[B, G, t, ·]`` and
     ``[B, t]`` leaves that attention reads."""
@@ -414,7 +431,10 @@ def attn_apply(arch: ArchConfig, p: dict, x: jax.Array, ctx=None, *,
     scan in ``models.lm.forward``) ``cache`` is the whole stacked grid the
     scan carries: the token's K/V row is written in place at
     ``[layer, row, :, pos % t]`` and attention reads layer ``layer`` of
-    the updated grid — the same values the per-layer write gives.
+    the updated grid — the same values the per-layer write gives. Where
+    :func:`ragged_decode_applies` (one TPU device, unquantised grid) and
+    attention is causal with no prefix, that read is the ragged Pallas
+    kernel, which copies in only each row's live K/V blocks.
 
     The grid is head-major (:func:`make_kv_cache`), and so is every K/V
     operand of the attention core; freshly projected K/V (prefill, the
@@ -475,14 +495,25 @@ def attn_apply(arch: ArchConfig, p: dict, x: jax.Array, ctx=None, *,
     elif cache is not None and s == 1:
         new_cache = _cache_write(cache, *_grid_rows(ctx, k, v), positions,
                                  layer)
-        view = new_cache if layer is None else _layer_view(new_cache, layer)
-        kv_valid = view["pos"] >= 0
-        o = L.decode_attention_sharded(ctx, q,
-                                       _kv_read(view, "k", q.dtype),
-                                       _kv_read(view, "v", q.dtype),
-                                       positions, view["pos"], kv_valid,
-                                       causal=causal, window=window,
-                                       prefix_len=prefix_len)
+        if (layer is not None and causal and prefix_len is None
+                and ragged_decode_applies(ctx, new_cache)):
+            # reads layer `layer` of the written grid where it lies, only
+            # each row's live blocks
+            from repro.kernels import ops
+            o = ops.ragged_decode_attn(q[:, 0], new_cache["k"],
+                                       new_cache["v"], new_cache["pos"],
+                                       layer, positions[:, 0],
+                                       window=window)[:, None]
+        else:
+            view = (new_cache if layer is None
+                    else _layer_view(new_cache, layer))
+            kv_valid = view["pos"] >= 0
+            o = L.decode_attention_sharded(ctx, q,
+                                           _kv_read(view, "k", q.dtype),
+                                           _kv_read(view, "v", q.dtype),
+                                           positions, view["pos"], kv_valid,
+                                           causal=causal, window=window,
+                                           prefix_len=prefix_len)
     else:
         kh, vh = k.swapaxes(1, 2), v.swapaxes(1, 2)  # [B, G, S, D]
         o = L.attention_sharded(ctx, q, kh, vh, positions, positions,

@@ -27,6 +27,8 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, ShapeConfig
 from repro.core.xfer import ShardingCtx
+from repro.kernels.ragged_decode_attention import live_blocks
+from repro.models import blocks as B
 from repro.models import encdec as ED
 from repro.models import lm as LM
 from repro.optim import adamw as OPT
@@ -77,6 +79,19 @@ def caches_quantized(caches: PyTree) -> bool:
         return any(walk(v) for v in node.values())
 
     return walk(caches)
+
+
+def body_kv_grid(arch: ArchConfig, caches: PyTree) -> Optional[dict]:
+    """The first attention grid of the scanned body of an LM's dense
+    cache tree (stacked ``[L, B, G, T, D]`` leaves): the grid that
+    single-token decode carries through the layer scan. None for
+    encoder-decoders, paged pools and bodies without attention."""
+    if arch.family == "encdec" or not isinstance(caches, dict):
+        return None
+    for key, grid in caches.get("body", {}).items():
+        if key.endswith("_attn") and "k" in grid:
+            return grid
+    return None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -370,6 +385,12 @@ def build_serve_step(arch: ArchConfig, ctx: Optional[ShardingCtx] = None, *,
                                         caches=caches,
                                         positions=state.positions)
             logits = LM.logits_fn(arch, params, hidden, ctx)
+        kv_blocks = None
+        grid = None if paged else body_kv_grid(arch, caches)
+        if grid is not None and B.ragged_decode_applies(ctx, grid):
+            # K/V blocks the ragged kernel read this step, for one layer
+            kv_blocks = jnp.sum(live_blocks(state.positions[:, 0],
+                                            grid["pos"].shape[-1]))
         rng, nxt = SMP.sample(logits[:, -1], state.rng, sampling)
         cur = state.tokens[:, 0]
         active = state.active
@@ -389,6 +410,8 @@ def build_serve_step(arch: ArchConfig, ctx: Optional[ShardingCtx] = None, *,
                      else state.seq_len + active.astype(jnp.int32)))
         record = {"token": jnp.where(emit, cur, -1), "emit": emit,
                   "finished": active & ~new_active}
+        if kv_blocks is not None:
+            record["kv_blocks"] = kv_blocks
         return state, caches, record
 
     return serve_step

@@ -35,6 +35,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
 from repro.core.execution_plan import ExecutionPlan
+from repro.kernels.ragged_decode_attention import block_size
 from repro.models import registry as REG
 from repro.quant import dequantize_params, quantize_params
 from repro.serving import spans as SP
@@ -325,6 +326,9 @@ class ServingEngine:
         # loop's one host↔device sync), in Scheduler.admit, and in the
         # serve step's dispatch; the same phases as the serve.* spans
         self.record_wait_times = deque(maxlen=4096)
+        # per retired record of a step that ran the ragged decode kernel:
+        # K/V blocks it read, summed over slots, for one layer
+        self.kv_blocks_read = deque(maxlen=4096)
         self.admit_times = deque(maxlen=4096)
         self.dispatch_times = deque(maxlen=4096)
         self._record_wait = 0.0
@@ -424,6 +428,8 @@ class ServingEngine:
             token = np.asarray(rec["token"])
             emit = np.asarray(rec["emit"])
             finished = np.asarray(rec["finished"])
+            if "kv_blocks" in rec:
+                self.kv_blocks_read.append(int(rec["kv_blocks"]))
         now = time.perf_counter()
         self._record_wait += now - t
         if token.ndim == 1:
@@ -715,6 +721,7 @@ class ServingEngine:
         self.retired_emits.clear()
         self.retired_active.clear()
         self.record_wait_times.clear()
+        self.kv_blocks_read.clear()
         self.admit_times.clear()
         self.dispatch_times.clear()
         self.scheduler.reset_stats()
@@ -731,7 +738,10 @@ class ServingEngine:
         up to ``k+1`` under speculation — the speedup lever). Speculative
         engines additionally report ``draft_acceptance``: accepted /
         proposed draft tokens over the currently-resident requests
-        (device counters, zeroed at admission)."""
+        (device counters, zeroed at admission). Where the serve step runs
+        the ragged decode kernel (one TPU device, unquantised dense grid),
+        ``kv_read_share`` is the share of the K/V grid it read: blocks
+        read × block size ÷ (slots × grid length), over retired steps."""
         from repro.core.stats import percentile
         ms = [t * 1e3 for t in self.step_times]
         total_s = sum(self.step_times)
@@ -754,6 +764,11 @@ class ServingEngine:
             "queue_depth": (sum(qd) / len(qd)) if qd else 0.0,
             "accepted_tokens_mean": (emits / actives) if actives else 0.0,
         }
+        if self.kv_blocks_read:
+            t = REG.body_kv_grid(self.arch, self.caches)["pos"].shape[-1]
+            stats["kv_read_share"] = (
+                sum(self.kv_blocks_read) * block_size(t)
+                / (len(self.kv_blocks_read) * self.slots * t))
         if self.spec is not None and self.state.accepted is not None:
             acc = float(np.asarray(self.state.accepted).sum())
             prop = float(np.asarray(self.state.proposed).sum())

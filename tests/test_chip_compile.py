@@ -338,3 +338,38 @@ def test_paged_serve_step_emits_kernel_on_tpu(topo, monkeypatch):
     compiled = _serve_step_compiled(topo, arch, slots=8, max_len=2048,
                                     paged=True)
     _assert_mosaic(compiled)
+
+
+def test_yi9b_l16_serve_step_runs_ragged_kernel(topo, monkeypatch):
+    """Built for a TPU, the one-chip ``yi-9b-l16`` serve step reads decode
+    attention through the ragged Pallas kernel: a ``tpu_custom_call`` that
+    takes the carried grid as it lies. The donated grid stays aliased,
+    temporaries stay under a sixteenth of one layer's K slab, and no
+    unfused ``copy``, ``transpose``, ``dynamic-slice`` or fusion has a
+    slab's element count: the kernel copies in blocks, never a slab."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    arch, slots, max_len = _yi9b_l16(), 32, 4096
+    compiled = _serve_step_compiled(topo, arch, slots=slots, max_len=max_len)
+    _assert_mosaic(compiled)
+    mem = compiled.memory_analysis()
+    shape = (arch.num_layers, slots, arch.num_kv_heads, max_len, arch.head_dim)
+    assert mem.alias_size_in_bytes >= 2 * int(np.prod(shape)) * 2
+    slab = slots * arch.num_kv_heads * max_len * arch.head_dim
+    assert mem.temp_size_in_bytes < slab * 2 / 16, (
+        f"temp {mem.temp_size_in_bytes / (1 << 20):.2f} MiB")
+    slab_ops = [(op, kind, shapes)
+                for op, kind, shapes in _unfused_ops(compiled.as_text())
+                if op in ("copy", "transpose", "dynamic-slice", "fusion")
+                and any(int(np.prod(sh)) == slab for sh in shapes)]
+    assert not slab_ops, slab_ops
+
+
+def test_yi9b_2x2_serve_step_keeps_flash_decoding(topo, monkeypatch):
+    """Built for a TPU, the four-chip ``yi-9b-2x2`` serve step, whose grid
+    is split by sequence, keeps flash-decoding: no Pallas kernel."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    compiled = _serve_step_compiled(topo, repro.get_arch("yi-9b"), slots=128,
+                                    max_len=2048, grid=(2, 2))
+    assert "tpu_custom_call" not in compiled.as_text()

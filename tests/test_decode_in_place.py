@@ -231,3 +231,88 @@ def test_paged_round_trip_matches_dense_grid(name, key):
         np.testing.assert_array_equal(np.asarray(h_p), np.asarray(h_d))
         tokens = jnp.argmax(LM.logits_fn(arch, params, h_d), axis=-1)
         positions = positions + 1
+
+
+def _interpret_ragged_kernel(monkeypatch):
+    """Build as for a TPU, so the dense-grid decode takes the ragged
+    kernel (``blocks.ragged_decode_applies``), and run that kernel in the
+    Pallas interpreter."""
+    from repro.kernels import ops
+    from repro.kernels import ragged_decode_attention as RK
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(
+        ops, "ragged_decode_attention",
+        lambda *a, interpret, **kw: RK.ragged_decode_attention(
+            *a, interpret=True, **kw))
+
+
+# heads of 128, the least the kernel's block copies tile
+RAGGED_ARCHS = {
+    # GQA rep 2 on a grid of 384: blocks of 128
+    "dense": dataclasses.replace(ARCHS["dense"][0], head_dim=128,
+                                 num_kv_heads=2),
+    # (rglru, rglru, attn) × 2 + an rglru suffix; every attention block a
+    # ring of the window's 128 positions (one block) that rows wrap
+    "hybrid-windowed": dataclasses.replace(
+        repro.get_arch("recurrentgemma-2b").reduced(), num_layers=7,
+        window=128, head_dim=128),
+}
+
+
+@pytest.mark.parametrize("name", list(RAGGED_ARCHS))
+def test_ragged_kernel_serve_step_matches_whole_slab_read(name, key,
+                                                         monkeypatch):
+    """The one-device fused serve step built for a TPU reads decode
+    attention through the ragged kernel. With rows that cross block
+    boundaries or wrap their ring, and an inert slot, 8 steps emit the
+    tokens of today's whole-slab read, the hidden states agree within the
+    flash kernel's float32 tolerance, and each record counts the blocks
+    the kernel read for one layer: Σ ceil(min(pos + 1, t) / tb) over the
+    slots."""
+    arch = RAGGED_ARCHS[name]
+    max_len, bucket, steps = 384, 256, 8
+    lens = np.array([100, 126, 5, 250], np.int32)
+    kp, kt = jax.random.split(key)
+    params = REG.init_params(arch, kp, jnp.float32)
+    prompt = jax.random.randint(kt, (SLOTS, bucket), 0, arch.vocab_size)
+    _, caches = jax.jit(lambda p, c, tok: LM.forward(
+        arch, p, tok, caches=c, seq_lens=jnp.asarray(lens)))(
+        params, REG.make_caches(arch, SLOTS, max_len, jnp.float32), prompt)
+    t = REG.body_kv_grid(arch, caches)["pos"].shape[-1]
+    assert t == {"dense": 384, "hybrid-windowed": 128}[name]
+    tb = 128  # the largest of 512, 256 and 128 that divides t
+    tokens = jax.random.randint(jax.random.fold_in(key, 1), (SLOTS, 1), 0,
+                                arch.vocab_size)
+    state = dataclasses.replace(
+        make_decode_state(SLOTS), tokens=tokens,
+        positions=jnp.asarray(lens)[:, None], active=jnp.asarray(ACTIVE),
+        max_new=jnp.full((SLOTS,), 100, jnp.int32))
+
+    def run():
+        """Hidden states of one decode step, and ``steps`` serve steps'
+        (positions, state, record); traced in the call."""
+        h, _ = jax.jit(lambda p, c, tok, pos: LM.forward(
+            arch, p, tok, caches=c, positions=pos))(params, caches, tokens,
+                                                    state.positions)
+        step = jax.jit(REG.build_serve_step(arch, sampling=GREEDY))
+        out, st, c = [], state, caches
+        for _ in range(steps):
+            pos = np.asarray(st.positions[:, 0])
+            st, c, rec = step(params, c, st)
+            out.append((pos, st, rec))
+        return h, out
+
+    h_slab, slab = run()
+    _interpret_ragged_kernel(monkeypatch)
+    h_ragged, ragged = run()
+    np.testing.assert_allclose(np.asarray(h_ragged), np.asarray(h_slab),
+                               rtol=2e-4, atol=2e-4)
+    for (pos, a, rec_a), (_, b, rec_b) in zip(slab, ragged):
+        assert "kv_blocks" not in rec_a
+        np.testing.assert_array_equal(np.asarray(rec_b["token"]),
+                                      np.asarray(rec_a["token"]))
+        np.testing.assert_array_equal(np.asarray(b.tokens),
+                                      np.asarray(a.tokens))
+        assert int(rec_b["kv_blocks"]) == int(
+            np.ceil(np.minimum(pos + 1, t) / tb).sum())
+    assert np.asarray(rec_a["emit"]).tolist() == ACTIVE.tolist()

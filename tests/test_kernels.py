@@ -192,3 +192,81 @@ def test_model_attention_matches_kernel(key):
     out_kernel = ops.attention(qf, kf, vf, causal=True, bq=64, bk=64)
     out_kernel = out_kernel.reshape(b, h, s, d).transpose(0, 2, 1, 3)
     np.testing.assert_allclose(out_model, out_kernel, rtol=2e-4, atol=2e-4)
+
+
+def _ragged_grid(key, *, h, g, q_pos, t=384, d=128, layers=3, window=0):
+    """A stacked head-major grid ``[L, B, G, t, d]`` (bf16) as the decode
+    scan carries it, with each row's ring filled through ``q_pos``: slot
+    ``s`` holds the newest position ``≡ s (mod t)`` at or below it. Past a
+    row's extent, odd rows hold stale positions above ``q_pos`` and even
+    rows empty slots (−1), both over finite garbage of magnitude 1e4 that
+    the mask must keep out."""
+    b = len(q_pos)
+    kq, kk, kv = jax.random.split(key, 3)
+    q = _rand(kq, (b, h, d), jnp.bfloat16)
+    k = _rand(kk, (layers, b, g, t, d), jnp.float32)
+    v = _rand(kv, (layers, b, g, t, d), jnp.float32)
+    qp = np.asarray(q_pos)[:, None]
+    s = np.arange(t)[None]
+    newest = qp - np.mod(qp - s, t)
+    live = newest >= 0
+    stale = np.where(np.arange(b)[:, None] % 2 == 1, s + t, -1)
+    pos = np.where(live, newest, stale).astype(np.int32)
+    junk = jnp.asarray(~live)[None, :, None, :, None]
+    k = jnp.where(junk, 1e4, k).astype(jnp.bfloat16)
+    v = jnp.where(junk, -1e4, v).astype(jnp.bfloat16)
+    pos = jnp.broadcast_to(jnp.asarray(pos)[None], (layers, b, t))
+    return q, k, v, pos, jnp.asarray(q_pos, jnp.int32)
+
+
+# (query heads, K/V heads): GQA rep 8 and rep 1
+_RAGGED_HEADS = {"rep8": (16, 2), "rep1": (4, 4)}
+# each row's query position; tb = 128 on a ring of 384
+_RAGGED_CASES = {
+    # extents 0, 1, tb-1, tb, tb+1 and t
+    "extents": (dict(q_pos=[-1, 0, 126, 127, 128, 383]), 1),
+    "layer0": (dict(q_pos=[5, 200, 300]), 0),
+    # rings that wrapped, read whole; with a window the mask cuts them
+    "wrapped": (dict(q_pos=[384, 500, 1000, 30]), 2),
+    "wrapped-window": (dict(q_pos=[384, 500, 1000, 30], window=200), 2),
+}
+
+
+@pytest.mark.parametrize("heads", list(_RAGGED_HEADS))
+@pytest.mark.parametrize("case", list(_RAGGED_CASES))
+def test_ragged_decode_attention(case, heads, key):
+    """The ragged kernel against ``layers.attention`` over the layer's
+    whole slab of the stacked head-major grid, masked as the decode path
+    masks it: equal within the flash kernel's bf16 tolerance, garbage
+    past each row's extent kept out, and a row with no live block
+    (extent 0) reads finite zeros."""
+    from repro.models import layers as L
+    kw, layer = _RAGGED_CASES[case]
+    h, g = _RAGGED_HEADS[heads]
+    window = kw.get("window", 0)
+    q, k, v, pos, q_pos = _ragged_grid(key, h=h, g=g, **kw)
+    out = ops.ragged_decode_attn(q, k, v, pos, jnp.int32(layer), q_pos,
+                                 window=window)
+    want = L.attention(q[:, None], k[layer], v[layer], q_pos[:, None],
+                       pos[layer], pos[layer] >= 0, window=window)[:, 0]
+    out, want = np.asarray(out, np.float32), np.asarray(want, np.float32)
+    assert np.isfinite(out).all()
+    empty = np.asarray(q_pos) < 0
+    np.testing.assert_array_equal(out[empty], 0.0)
+    np.testing.assert_allclose(out[~empty], want[~empty],
+                               **_TOL[jnp.bfloat16])
+
+
+def test_ragged_decode_attention_ref(key):
+    """The jnp oracle in ``kernels/ref.py`` (whole slab, float32) and the
+    kernel agree on every case at once: extents, a wrapped row, a window."""
+    q, k, v, pos, q_pos = _ragged_grid(
+        key, h=16, g=2, q_pos=[-1, 0, 127, 128, 383, 700])
+    for window in (0, 100):
+        out = ops.ragged_decode_attn(q, k, v, pos, jnp.int32(2), q_pos,
+                                     window=window)
+        ref = ops.ragged_decode_attn_ref(q, k, v, pos, 2, q_pos,
+                                         window=window)
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref, np.float32),
+                                   **_TOL[jnp.bfloat16])

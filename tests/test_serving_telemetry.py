@@ -1,6 +1,7 @@
 """The serving loop's own telemetry: the ``serve.*`` profiler spans, the
 per-step phase counters, the request stamps, and the jitted program names
 that device-trace readers match."""
+import dataclasses
 import glob
 import importlib.util
 import pathlib
@@ -231,3 +232,34 @@ def test_engine_prefill_timing_hooks(key):
     assert stats["prefill_tokens_per_s"] > 0
     engine.reset_step_stats()
     assert engine.prefill_stats()["prefills"] == 0.0
+
+
+def test_kv_read_share_counts_the_ragged_kernel_blocks(monkeypatch):
+    """``step_stats()["kv_read_share"]``: on a one-device plan built for a
+    TPU (the ragged kernel interpreted), every short request lives in the
+    first 128-position block of a 384-position grid, inert slots too, so
+    the kernel reads a third of it. Without the kernel (this CPU's own
+    build) the record has no block count and the share is absent."""
+    from repro.kernels import ops
+    from repro.kernels import ragged_decode_attention as RK
+
+    arch = dataclasses.replace(ARCH, head_dim=128)  # the kernel's least
+
+    def engine():
+        plan = repro.plan(arch, ShapeConfig("telemetry", 384, 2, "decode"))
+        return plan.compile().serve(config=ServeConfig(slots=2, max_len=384))
+
+    eng = engine()
+    _submit(eng, [0, 1, 2])
+    _steps_until_idle(eng)
+    assert "kv_read_share" not in eng.step_stats()
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(
+        ops, "ragged_decode_attention",
+        lambda *a, interpret, **kw: RK.ragged_decode_attention(
+            *a, interpret=True, **kw))
+    eng = engine()
+    _submit(eng, [0, 1, 2])
+    _steps_until_idle(eng)
+    assert eng.step_stats()["kv_read_share"] == pytest.approx(1 / 3)
